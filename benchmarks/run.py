@@ -121,6 +121,7 @@ def write_bench_json(mod_name: str, rows, seconds: float, status: str,
 def main() -> None:
     import importlib
 
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.obs import metrics as obs_metrics
     from repro.obs import trace as obs_trace
 
@@ -130,6 +131,7 @@ def main() -> None:
                          "(BENCH_*.json points at the files)")
     args = ap.parse_args()
 
+    configure_compile_cache()
     env = env_provenance()
     print("name,us_per_call,derived")
     failures = 0

@@ -211,6 +211,19 @@ def pow2_factors(e: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     return f1, f2
 
 
+def floor_log2(x: jnp.ndarray) -> jnp.ndarray:
+    """``floor(log2(x))`` as int32, read from the f32 exponent field.
+
+    Exact for positive normal ``x``.  XLA's ``log2`` is an approximation
+    whose floor flips at and just below powers of two, and differently on
+    each backend; the exponent field is the same bits everywhere, so plane
+    counts derived from a tolerance agree between the host encoder and the
+    TPU kernel.  Zero and subnormal ``x`` read as -127.
+    """
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return ((bits >> 23) & 0xFF) - 127
+
+
 def scale_by_pow2(x: jnp.ndarray, e: jnp.ndarray) -> jnp.ndarray:
     """``x * 2^e`` via two exact power-of-two multiplies (see pow2_factors)."""
     f1, f2 = pow2_factors(e)

@@ -130,8 +130,7 @@ def encode_fixed_rate_batch(xs: jnp.ndarray, bits_per_value: int,
 # ---------------------------------------------------------------------------
 
 def _planes_for_tolerance(emax: jnp.ndarray, tol: jnp.ndarray) -> jnp.ndarray:
-    log2tol = jnp.floor(jnp.log2(tol)).astype(jnp.int32)
-    b = emax - log2tol + GUARD_BITS
+    b = emax - T.floor_log2(tol) + GUARD_BITS
     return jnp.clip(b, 0, T.TOTAL_PLANES).astype(jnp.int32)
 
 

@@ -1,8 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: compiled Pallas on TPU, ``interpret=True`` elsewhere (this
-container is CPU-only; interpret mode runs the kernel body in Python and is
-used for correctness validation against ref.py).
+Dispatch policy: every wrapper chooses its path by the platform it is
+lowered for (``jax.lax.platform_dependent``), not by the process's default
+backend, so a program compiled for a TPU carries the compiled kernel even
+when the compile runs in a CPU process.
+
+  * plain wrappers (``zfp_decode_blocks`` ...): compiled Pallas on TPU,
+    the same kernel in interpret mode elsewhere -- the correctness path the
+    tests validate against ref.py;
+  * ``*_fast`` wrappers: compiled Pallas on TPU, the jitted jnp oracle
+    elsewhere (interpret mode runs the kernel body in Python, far too slow
+    for the training and datagen hot paths).  The oracle is bit-identical
+    to the kernel (tests assert so).
 """
 from __future__ import annotations
 
@@ -13,104 +22,94 @@ import jax.numpy as jnp
 
 from repro.compression import transform as T
 from repro.compression.zfp import CompressedField
+from repro.kernels import ref
 from repro.kernels import zfp_codec
 from repro.kernels import flash_attention as _fa
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _kernel_on_tpu(kernel, other, *args):
+    """``kernel(*args)`` when lowered for a TPU, ``other(*args)`` otherwise."""
+    return jax.lax.platform_dependent(*args, tpu=kernel, default=other)
 
 
 def zfp_decode_blocks(payload, emax, bits_per_value):
-    return zfp_codec.zfp_decode_blocks(payload, emax, bits_per_value,
-                                       interpret=_interpret())
+    kernel = partial(zfp_codec.zfp_decode_blocks, bits_per_value=bits_per_value)
+    return _kernel_on_tpu(kernel, partial(kernel, interpret=True),
+                          payload, emax)
 
 
 def zfp_decode_blocks_fast(payload, emax, bits_per_value):
-    """Throughput path: compiled Pallas on TPU, compiled jnp oracle on CPU.
-
-    Interpret-mode Pallas executes the kernel body in Python -- fine for
-    correctness validation, wrong for measuring pipeline throughput.  The
-    oracle is jit-compiled XLA and numerically identical (tests assert so).
-    """
-    if _interpret():
-        return _ref_decode_jit(payload, emax)
-    return zfp_codec.zfp_decode_blocks(payload, emax, bits_per_value)
+    """Fixed-rate decode for throughput: kernel on TPU, oracle elsewhere."""
+    return _kernel_on_tpu(
+        partial(zfp_codec.zfp_decode_blocks, bits_per_value=bits_per_value),
+        _ref_decode_jit, payload, emax)
 
 
 @jax.jit
 def _ref_decode_jit(payload, emax):
-    from repro.kernels import ref
     return ref.zfp_decode_blocks_ref(payload, emax, payload.shape[1] * 2)
 
 
 def zfp_decode_blocks_fa(payload, emax, nplanes):
     """Fixed-accuracy decode (per-block variable plane counts), kernel path."""
-    return zfp_codec.zfp_decode_blocks_fa(payload, emax, nplanes,
-                                          interpret=_interpret())
+    return _kernel_on_tpu(
+        zfp_codec.zfp_decode_blocks_fa,
+        partial(zfp_codec.zfp_decode_blocks_fa, interpret=True),
+        payload, emax, nplanes)
 
 
 def zfp_decode_blocks_fa_fast(payload, emax, nplanes):
-    """Throughput path for the fixed-accuracy decode.
+    """Fixed-accuracy decode for throughput: kernel on TPU, oracle elsewhere.
 
-    Compiled Pallas on TPU, compiled jnp oracle elsewhere (interpret-mode
-    Pallas runs the kernel body in Python — correct but far too slow for the
-    device-resident training hot path).  Numerically identical to the kernel
-    path; this is what the fused gather→decode train step traces through.
+    This is what the fused gather -> decode train step traces through.
     """
-    if _interpret():
-        return _ref_decode_fa_jit(payload, emax, nplanes)
-    return zfp_codec.zfp_decode_blocks_fa(payload, emax, nplanes)
+    return _kernel_on_tpu(zfp_codec.zfp_decode_blocks_fa, _ref_decode_fa_jit,
+                          payload, emax, nplanes)
 
 
 @jax.jit
 def _ref_decode_fa_jit(payload, emax, nplanes):
-    from repro.kernels import ref
     return ref.zfp_decode_blocks_fa_ref(payload, emax, nplanes)
 
 
 def zfp_encode_blocks(blocks, bits_per_value):
-    return zfp_codec.zfp_encode_blocks(blocks, bits_per_value,
-                                       interpret=_interpret())
+    kernel = partial(zfp_codec.zfp_encode_blocks, bits_per_value=bits_per_value)
+    return _kernel_on_tpu(kernel, partial(kernel, interpret=True), blocks)
 
 
 def zfp_encode_blocks_fast(blocks, bits_per_value):
-    """Throughput path for the fixed-rate encode: compiled Pallas on TPU,
-    compiled jnp oracle elsewhere (interpret mode is a correctness tool)."""
-    if _interpret():
-        return _ref_encode_jit(blocks, bits_per_value)
-    return zfp_codec.zfp_encode_blocks(blocks, bits_per_value)
+    """Fixed-rate encode for throughput: kernel on TPU, oracle elsewhere."""
+    return _kernel_on_tpu(
+        partial(zfp_codec.zfp_encode_blocks, bits_per_value=bits_per_value),
+        partial(_ref_encode_jit, bits_per_value=bits_per_value), blocks)
 
 
 @partial(jax.jit, static_argnames=("bits_per_value",))
 def _ref_encode_jit(blocks, bits_per_value):
-    from repro.kernels import ref
     return ref.zfp_encode_blocks_ref(blocks, bits_per_value)
 
 
 def zfp_encode_blocks_fa(blocks, tols):
     """Fixed-accuracy encode (per-block L-inf tolerances), kernel path."""
-    return zfp_codec.zfp_encode_blocks_fa(blocks, tols,
-                                          interpret=_interpret())
+    return _kernel_on_tpu(
+        zfp_codec.zfp_encode_blocks_fa,
+        partial(zfp_codec.zfp_encode_blocks_fa, interpret=True),
+        blocks, tols)
 
 
 def zfp_encode_blocks_fa_fast(blocks, tols):
-    """Throughput path for the fixed-accuracy encode.
+    """Fixed-accuracy encode for throughput: kernel on TPU, oracle elsewhere.
 
-    Compiled Pallas on TPU, compiled jnp oracle elsewhere — the dispatch
-    mirror of ``zfp_decode_blocks_fa_fast``.  Bit-identical to the kernel
-    path (tests assert payload/emax/nplanes equality), so the codec seam's
-    ``backend="pallas"`` encode and the datagen encode-on-device path can
-    use it unconditionally.
+    Bit-identical to the kernel path (tests assert payload/emax/nplanes
+    equality), so the codec seam's ``backend="pallas"`` encode and the
+    datagen encode-on-device path use it unconditionally.
     """
-    if _interpret():
-        return _ref_encode_fa_jit(blocks, tols)
-    return zfp_codec.zfp_encode_blocks_fa(blocks, tols)
+    return _kernel_on_tpu(zfp_codec.zfp_encode_blocks_fa, _ref_encode_fa_jit,
+                          blocks, tols)
 
 
 @jax.jit
 def _ref_encode_fa_jit(blocks, tols):
-    from repro.kernels import ref
     return ref.zfp_encode_blocks_fa_ref(blocks, tols)
 
 
@@ -134,5 +133,6 @@ def encode_field(x: jnp.ndarray, bits_per_value: int) -> CompressedField:
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, window=None):
-    return _fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               window=window, interpret=_interpret())
+    kernel = partial(_fa.flash_attention, causal=causal, sm_scale=sm_scale,
+                     window=window)
+    return _kernel_on_tpu(kernel, partial(kernel, interpret=True), q, k, v)

@@ -57,8 +57,7 @@ def zfp_encode_blocks_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
     qi = T.quantize_blocks(blocks_f, emax)
     u_full = T.int2nb(T.fwd_transform_2d(qi))
     tols = jnp.asarray(tols, jnp.float32)
-    log2tol = jnp.floor(jnp.log2(tols)).astype(jnp.int32)
-    npl = jnp.clip(emax - log2tol + GUARD_BITS, 0,
+    npl = jnp.clip(emax - T.floor_log2(tols) + GUARD_BITS, 0,
                    T.TOTAL_PLANES).astype(jnp.int32)
     npl = jnp.where(jnp.all(u_full == 0, axis=-1), 0, npl)
 

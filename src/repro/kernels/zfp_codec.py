@@ -27,6 +27,7 @@ from repro.compression.transform import (
     MAX_WORDS,
     Q_FIXED_POINT,
     TOTAL_PLANES,
+    floor_log2,
     scale_by_pow2,
 )
 from repro.compression.zfp import GUARD_BITS, MAX_FIX_ITERS
@@ -71,24 +72,74 @@ def _fwd_lift4(x, y, z, w):
     return x, y, z, w
 
 
+def _tile_emax(x):
+    """(BT, 16) f32 -> (BT, 1) int32 frexp exponent of each block's max |x|
+    (``transform.block_emax``, read from the exponent field)."""
+    maxabs = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
+    return jnp.where(maxabs >= 2.0 ** -120, floor_log2(maxabs) + 1, 0)
+
+
+def _transpose_blocks(a):
+    """(BT, 16) row-major 4x4 blocks -> their transposes (lane 4r+c <-> 4c+r).
+
+    Built from one-lane static slices: Mosaic lowers a lane-strided slice
+    (``a[:, 0::4]``) or a ``(BT, 4, 4)`` reshape as a gather it refuses.
+    """
+    return jnp.concatenate([a[:, 4 * c + r:4 * c + r + 1]
+                            for r in range(4) for c in range(4)], axis=-1)
+
+
+def _lift_y(a, lift):
+    """Apply ``lift`` along y: across the four rows of each block, which are
+    its contiguous 4-lane groups."""
+    x, y, z, w = lift(*[a[:, 4 * k:4 * k + 4] for k in range(4)])
+    return jnp.concatenate([x, y, z, w], axis=-1)
+
+
 def _inv_transform_tile(coef):
-    """(BT, 16) int32 inverse 2D lift, slicing lanes statically."""
-    rows = [coef[:, 0:4], coef[:, 4:8], coef[:, 8:12], coef[:, 12:16]]
-    x, y, z, w = _inv_lift4(*rows)
-    b = jnp.concatenate([x, y, z, w], axis=-1)
-    cols = [b[:, 0::4], b[:, 1::4], b[:, 2::4], b[:, 3::4]]
-    x, y, z, w = _inv_lift4(*cols)
-    out = jnp.stack([x, y, z, w], axis=-1)            # (BT, 4, 4)
-    return out.reshape(coef.shape[0], 16)
+    """(BT, 16) int32 inverse 2D lift: columns, then rows (via transposes)."""
+    b = _lift_y(coef, _inv_lift4)
+    return _transpose_blocks(_lift_y(_transpose_blocks(b), _inv_lift4))
 
 
 def _fwd_transform_tile(qi):
-    cols = [qi[:, 0::4], qi[:, 1::4], qi[:, 2::4], qi[:, 3::4]]
-    x, y, z, w = _fwd_lift4(*cols)
-    b = jnp.stack([x, y, z, w], axis=-1).reshape(qi.shape[0], 16)
-    rows = [b[:, 0:4], b[:, 4:8], b[:, 8:12], b[:, 12:16]]
-    x, y, z, w = _fwd_lift4(*rows)
-    return jnp.concatenate([x, y, z, w], axis=-1)
+    """(BT, 16) int32 forward 2D lift: rows (via transposes), then columns."""
+    b = _transpose_blocks(_lift_y(_transpose_blocks(qi), _fwd_lift4))
+    return _lift_y(b, _fwd_lift4)
+
+
+def _unpack_tile(payload, num_words):
+    """(BT, W) plane words -> (BT, 16) negabinary; inverse of ``_pack_tile``."""
+    lanes = _lanes16()
+    u = jnp.zeros((payload.shape[0], 16), jnp.int32)
+    for k in range(num_words):                        # static unroll
+        word = payload[:, k:k + 1]                    # (BT, 1)
+        p_hi = TOTAL_PLANES - 1 - 2 * k
+        p_lo = TOTAL_PLANES - 2 - 2 * k
+        u = u | (((word >> lanes) & 1) << p_hi)
+        if p_lo >= 0:
+            u = u | (((word >> (lanes + 16)) & 1) << p_lo)
+    return u
+
+
+def _pack_tile(u, num_words):
+    """(BT, 16) negabinary -> (BT, num_words) plane words, MSB plane first.
+
+    Each word is a keepdims lane reduction, and the tile is stored once:
+    Mosaic refuses 1-D column stores into the payload ref.
+    """
+    lanes = _lanes16()
+    words = []
+    for k in range(num_words):
+        p_hi = TOTAL_PLANES - 1 - 2 * k
+        p_lo = TOTAL_PLANES - 2 - 2 * k
+        word = jnp.sum(((u >> p_hi) & 1) << lanes, axis=-1, keepdims=True,
+                       dtype=jnp.int32)
+        if p_lo >= 0:
+            word = word | (jnp.sum(((u >> p_lo) & 1) << lanes, axis=-1,
+                                   keepdims=True, dtype=jnp.int32) << 16)
+        words.append(word)
+    return jnp.concatenate(words, axis=-1)
 
 
 # ---------------------------------------------------------------------------
@@ -98,15 +149,7 @@ def _fwd_transform_tile(qi):
 def _decode_kernel(payload_ref, emax_ref, out_ref, *, num_words):
     payload = payload_ref[...]                        # (BT, W) int32
     emax = emax_ref[...]                              # (BT, 1) int32
-    lanes = _lanes16()
-    u = jnp.zeros((payload.shape[0], 16), jnp.int32)
-    for k in range(num_words):                        # static unroll
-        word = payload[:, k][:, None]                 # (BT, 1)
-        p_hi = TOTAL_PLANES - 1 - 2 * k
-        p_lo = TOTAL_PLANES - 2 - 2 * k
-        u = u | (((word >> lanes) & 1) << p_hi)
-        if p_lo >= 0:
-            u = u | (((word >> (lanes + 16)) & 1) << p_lo)
+    u = _unpack_tile(payload, num_words)
     neg = jnp.int32(_NEG)
     coef = (u ^ neg) - neg                            # negabinary -> int
     qi = _inv_transform_tile(coef)
@@ -151,15 +194,7 @@ def _decode_fa_kernel(payload_ref, emax_ref, nplanes_ref, out_ref, *,
     payload = payload_ref[...]                        # (BT, W) int32
     emax = emax_ref[...]                              # (BT, 1) int32
     npl = nplanes_ref[...]                            # (BT, 1) int32
-    lanes = _lanes16()
-    u = jnp.zeros((payload.shape[0], 16), jnp.int32)
-    for k in range(num_words):                        # static unroll
-        word = payload[:, k][:, None]                 # (BT, 1)
-        p_hi = TOTAL_PLANES - 1 - 2 * k
-        p_lo = TOTAL_PLANES - 2 - 2 * k
-        u = u | (((word >> lanes) & 1) << p_hi)
-        if p_lo >= 0:
-            u = u | (((word >> (lanes + 16)) & 1) << p_lo)
+    u = _unpack_tile(payload, num_words)
     shift = jnp.clip(TOTAL_PLANES - npl, 0, 31)       # (BT, 1), broadcasts
     u = u & (jnp.int32(-1) << shift)                  # zero dropped planes
     neg = jnp.int32(_NEG)
@@ -208,40 +243,27 @@ def zfp_decode_blocks_fa(payload: jnp.ndarray, emax: jnp.ndarray,
 
 def _encode_kernel(blocks_ref, payload_ref, emax_ref, *, num_words, bits):
     x = blocks_ref[...]                               # (BT, 16) f32
-    maxabs = jnp.max(jnp.abs(x), axis=-1, keepdims=True)   # (BT, 1)
-    # frexp exponent via bit twiddling: x = m 2^e, m in [0.5, 1)
-    mbits = jax.lax.bitcast_convert_type(maxabs, jnp.int32)
-    e = ((mbits >> 23) & 0xFF) - 126
-    emax = jnp.where(maxabs >= 2.0 ** -120, e, 0).astype(jnp.int32)
+    emax = _tile_emax(x)                              # (BT, 1) int32
     qi = jnp.round(scale_by_pow2(x, Q_FIXED_POINT - emax)).astype(jnp.int32)
     coef = _fwd_transform_tile(qi)
     neg = jnp.int32(_NEG)
     u = (coef + neg) ^ neg                            # int -> negabinary
     shift = TOTAL_PLANES - bits
     u = u & (jnp.int32(-1) << shift)                  # truncate planes
-    lanes = _lanes16()
-    for k in range(num_words):
-        p_hi = TOTAL_PLANES - 1 - 2 * k
-        p_lo = TOTAL_PLANES - 2 - 2 * k
-        plane_hi = jnp.sum(((u >> p_hi) & 1) << lanes, axis=-1, dtype=jnp.int32)
-        if p_lo >= 0:
-            plane_lo = jnp.sum(((u >> p_lo) & 1) << lanes, axis=-1, dtype=jnp.int32)
-        else:
-            plane_lo = jnp.zeros_like(plane_hi)
-        payload_ref[:, k] = plane_hi | (plane_lo << 16)
+    payload_ref[...] = _pack_tile(u, num_words)
     emax_ref[...] = emax
 
 
-def _encode_fa_kernel(blocks_ref, tol_ref, log2tol_ref, payload_ref,
-                      emax_ref, nplanes_ref):
+def _encode_fa_kernel(blocks_ref, tol_ref, payload_ref, emax_ref,
+                      nplanes_ref):
     """Fixed-accuracy encode tile: the full error-bounded pipeline in VMEM.
 
     Same quantize → forward lift → negabinary front end as
     ``_encode_kernel``, then the per-block plane-count guess
     (``_planes_for_tolerance``: ``emax - floor(log2(tol)) + GUARD_BITS``,
-    with ``floor(log2(tol))`` precomputed OUTSIDE the kernel so both
-    backends share one fp log2 evaluation) and the bound-verification
-    correction as a static ``MAX_FIX_ITERS``-deep in-register loop — the
+    with the floor read from the exponent field, exact on every backend)
+    and the bound-verification correction as a static
+    ``MAX_FIX_ITERS``-deep in-register loop — the
     jnp encoder's while_loop runs the identical body at most that many
     times and the body is a no-op on settled blocks, so unrolling is
     bit-exact.  The final variable-plane pack masks via each block's
@@ -249,18 +271,13 @@ def _encode_fa_kernel(blocks_ref, tol_ref, log2tol_ref, payload_ref,
     """
     x = blocks_ref[...]                               # (BT, 16) f32
     tol = tol_ref[...]                                # (BT, 1) f32
-    log2tol = log2tol_ref[...]                        # (BT, 1) i32
-    maxabs = jnp.max(jnp.abs(x), axis=-1, keepdims=True)   # (BT, 1)
-    # frexp exponent via bit twiddling: x = m 2^e, m in [0.5, 1)
-    mbits = jax.lax.bitcast_convert_type(maxabs, jnp.int32)
-    e = ((mbits >> 23) & 0xFF) - 126
-    emax = jnp.where(maxabs >= 2.0 ** -120, e, 0).astype(jnp.int32)
+    emax = _tile_emax(x)                              # (BT, 1) int32
     qi = jnp.round(scale_by_pow2(x, Q_FIXED_POINT - emax)).astype(jnp.int32)
     coef = _fwd_transform_tile(qi)
     neg = jnp.int32(_NEG)
     u_full = (coef + neg) ^ neg                       # int -> negabinary
 
-    npl = jnp.clip(emax - log2tol + GUARD_BITS, 0, TOTAL_PLANES)
+    npl = jnp.clip(emax - floor_log2(tol) + GUARD_BITS, 0, TOTAL_PLANES)
     npl = jnp.where(jnp.all(u_full == 0, axis=-1, keepdims=True), 0, npl)
     for _ in range(MAX_FIX_ITERS):                    # static unroll
         shift = jnp.clip(TOTAL_PLANES - npl, 0, 31)
@@ -273,16 +290,7 @@ def _encode_fa_kernel(blocks_ref, tol_ref, log2tol_ref, payload_ref,
 
     shift = jnp.clip(TOTAL_PLANES - npl, 0, 31)
     u = u_full & (jnp.int32(-1) << shift)             # truncate kept planes
-    lanes = _lanes16()
-    for k in range(MAX_WORDS):
-        p_hi = TOTAL_PLANES - 1 - 2 * k
-        p_lo = TOTAL_PLANES - 2 - 2 * k
-        plane_hi = jnp.sum(((u >> p_hi) & 1) << lanes, axis=-1, dtype=jnp.int32)
-        if p_lo >= 0:
-            plane_lo = jnp.sum(((u >> p_lo) & 1) << lanes, axis=-1, dtype=jnp.int32)
-        else:
-            plane_lo = jnp.zeros_like(plane_hi)
-        payload_ref[:, k] = plane_hi | (plane_lo << 16)
+    payload_ref[...] = _pack_tile(u, MAX_WORDS)
     emax_ref[...] = emax
     nplanes_ref[...] = npl
 
@@ -300,22 +308,16 @@ def zfp_encode_blocks_fa(blocks: jnp.ndarray, tols: jnp.ndarray,
     """
     nb = blocks.shape[0]
     tols = jnp.asarray(tols, jnp.float32)
-    # one fp log2 evaluation shared with the jnp encoder's formula — inside
-    # the kernel a different log2 lowering could flip the floor at exact
-    # powers of two
-    log2tols = jnp.floor(jnp.log2(tols)).astype(jnp.int32)
     pad = (-nb) % BLOCK_TILE
     if pad:
         blocks = jnp.pad(blocks, ((0, pad), (0, 0)))
         tols = jnp.pad(tols, ((0, pad),), constant_values=1.0)
-        log2tols = jnp.pad(log2tols, ((0, pad),))
     nbp = blocks.shape[0]
     payload, emax, nplanes = pl.pallas_call(
         _encode_fa_kernel,
         grid=(nbp // BLOCK_TILE,),
         in_specs=[
             pl.BlockSpec((BLOCK_TILE, 16), lambda i: (i, 0)),
-            pl.BlockSpec((BLOCK_TILE, 1), lambda i: (i, 0)),
             pl.BlockSpec((BLOCK_TILE, 1), lambda i: (i, 0)),
         ],
         out_specs=[
@@ -329,7 +331,7 @@ def zfp_encode_blocks_fa(blocks: jnp.ndarray, tols: jnp.ndarray,
             jax.ShapeDtypeStruct((nbp, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(blocks, tols[:, None], log2tols[:, None])
+    )(blocks, tols[:, None])
     return payload[:nb], emax[:nb, 0], nplanes[:nb, 0]
 
 

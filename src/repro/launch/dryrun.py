@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# ^ MUST precede any jax-importing module: jax locks the device count on
-# first init.  512 placeholder host devices back the production meshes.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# ^ MUST precede any jax-importing module: jax locks the platform and the
+# device count on first init.  512 placeholder host devices back the
+# production meshes, and the dry run only compiles, so it never takes a chip.
 
 import argparse
 import json
@@ -124,7 +126,6 @@ def make_train_step_podcompressed(cfg: ArchConfig, mesh, pspecs,
     makes the exchange error-bounded instead of rate-bounded.
     Error-feedback residual carry is available in repro.core.grad_compress
     for real training runs."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.compression import decode_tree, encode_tree
     from repro.core.grad_compress import as_codec
@@ -155,7 +156,7 @@ def make_train_step_podcompressed(cfg: ArchConfig, mesh, pspecs,
         mean = jax.tree_util.tree_unflatten(treedef,
                                             [a / n_pod for a in acc])
         # out_specs omit 'pod': every pod decoded the same payloads, so the
-        # mean is pod-replicated by construction (check_rep off)
+        # mean is pod-replicated by construction (check_vma off)
         return jax.tree.map(lambda m, g: m.astype(g.dtype),
                             mean, jax.tree.map(lambda g: g[0], grads_pods))
 
@@ -168,9 +169,9 @@ def make_train_step_podcompressed(cfg: ArchConfig, mesh, pspecs,
             losses, grads = jax.vmap(
                 lambda b: jax.value_and_grad(lm.lm_loss)(params, cfg, b),
                 spmd_axis_name="pod")(batch_pods)
-            grads = shard_map(exchange, mesh,
-                              in_specs=(pod_specs,), out_specs=pspecs,
-                              check_rep=False, auto=frozenset())(grads)
+            grads = jax.shard_map(exchange, mesh=mesh,
+                                  in_specs=(pod_specs,), out_specs=pspecs,
+                                  check_vma=False)(grads)
             grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
             params, opt_state = adam_update(grads, opt_state, params, opt_cfg)
             return params, opt_state, jnp.mean(losses)

@@ -27,6 +27,7 @@ import argparse
 import jax
 
 from repro.configs import reduced_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import lm
 from repro.obs import trace as obs_trace
 from repro.serving import ServeEngine, SurrogateServeEngine
@@ -101,6 +102,7 @@ def main() -> None:
                     help="enable telemetry: write <run>.trace.json "
                          "(Perfetto-loadable) + <run>.events.jsonl here")
     args = ap.parse_args()
+    configure_compile_cache()
     if args.trace_dir:
         obs_trace.configure(args.trace_dir, run=f"serve_{args.mode}")
     (serve_lm if args.mode == "lm" else serve_surrogate)(args)

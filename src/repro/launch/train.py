@@ -51,6 +51,7 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
     from repro.configs import reduced_config
+    from repro.launch.compile_cache import configure_compile_cache
     from repro.models import lm
     from repro.obs import jaxprof
     from repro.obs import metrics as obs_metrics
@@ -58,6 +59,7 @@ def main() -> None:
     from repro.train import checkpoint as ckpt
     from repro.train.optimizer import AdamConfig, adam_init, adam_update
 
+    configure_compile_cache()
     if args.trace_dir:
         obs_trace.configure(args.trace_dir, run=f"train_{args.arch}")
 

@@ -24,7 +24,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.interpreters import batching as _batching
 
 from repro.configs.base import ArchConfig
 
@@ -204,36 +203,10 @@ def _constrain(x, *spec):
 DP = ("pod", "data")     # batch axes (filtered against the ambient mesh)
 
 
-@jax.custom_jvp
-def _reduce_barrier(x):
-    """Keep TP partial-sum reductions in bf16 (§Perf iteration 1).
-
-    XLA's SPMD partitioner may hoist a consumer's f32 upcast above the
-    GSPMD-inserted all-reduce, doubling wire bytes.  An optimization barrier
-    between the (bf16) partial product and the upcasting consumer pins the
-    collective to bf16.
-
-    jax 0.4.37's ``optimization_barrier`` primitive has neither a JVP nor a
-    transpose rule, so the barrier is wrapped in a custom_jvp that passes the
-    tangent through untouched: the primal keeps the bf16-collective pin while
-    gradients see an identity (the tangent cannot be barriered — its
-    transpose would hit the same missing rule)."""
-    return jax.lax.optimization_barrier(x)
-
-
-@_reduce_barrier.defjvp
-def _reduce_barrier_jvp(primals, tangents):
-    return _reduce_barrier(primals[0]), tangents[0]
-
-
-# jax 0.4.37 also ships no vmap rule for the barrier; it is elementwise, so
-# batching is the identity on batch dims.  Needed for the per-pod
-# vmap(spmd_axis_name='pod') gradient path in launch/dryrun.py.
-if jax.lax.optimization_barrier_p not in _batching.primitive_batchers:
-    def _barrier_batcher(args, dims):
-        return jax.lax.optimization_barrier_p.bind(*args), dims
-    _batching.primitive_batchers[jax.lax.optimization_barrier_p] = \
-        _barrier_batcher
+# TP partial products pass through ``jax.lax.optimization_barrier`` before
+# any consumer upcasts them: XLA's SPMD partitioner may otherwise hoist the
+# f32 upcast above the GSPMD-inserted all-reduce, doubling wire bytes.  The
+# barrier pins the collective to bf16.
 
 # Per-layer gathered-weight specs: weights arrive FSDP-sharded over "data";
 # constraining them to their TP-only spec forces GSPMD into the ZeRO-3
@@ -320,7 +293,8 @@ def attention(q, k, v, qpos, kpos, *, causal=True, window=None, chunk=1024,
 def swiglu(x, w_gate, w_up, w_down):
     g = _constrain(jnp.einsum("bsd,df->bsf", x, w_gate), DP, None, "model")
     u = _constrain(jnp.einsum("bsd,df->bsf", x, w_up), DP, None, "model")
-    return _reduce_barrier(jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, w_down))
+    return jax.lax.optimization_barrier(
+        jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, w_down))
 
 
 # ===========================================================================
@@ -369,7 +343,7 @@ def moe_block(lp, x, cfg: ArchConfig):
     h = _constrain(h, DP, "model", None, None)
     ye = _constrain(jnp.einsum("gecf,efd->gecd", h, lp["e_down"]),
                     DP, "model", None, None)
-    y = _reduce_barrier(
+    y = jax.lax.optimization_barrier(
         jnp.einsum("gnec,gecd->gnd", combine, ye)).reshape(b, s, d)
 
     # load-balance loss (Switch): e * sum_e f_e * p_e
@@ -508,7 +482,8 @@ def ssm_block(lp, x, cfg: ArchConfig, conv_state=None, ssm_state=None,
     y = y + xh.astype(jnp.float32) * lp["ssm_D"][None, None, :, None]
     y = y.reshape(*x.shape[:2], di).astype(x.dtype)
     y = rmsnorm(lp["ssm_norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    out = _reduce_barrier(jnp.einsum("bse,ed->bsd", y, lp["ssm_out"]))
+    out = jax.lax.optimization_barrier(
+        jnp.einsum("bse,ed->bsd", y, lp["ssm_out"]))
     return out, (new_conv, final_state)
 
 
@@ -562,7 +537,8 @@ def attn_block(lp, x, cfg: ArchConfig, positions, *, causal=True,
                       window=cfg.attn_window or None, window_dyn=window_dyn,
                       chunk=cfg.attn_chunk)
         new_kv = (k, v)
-    return _reduce_barrier(jnp.einsum("bshe,hed->bsd", y, lp["wo"])), new_kv
+    return jax.lax.optimization_barrier(
+        jnp.einsum("bshe,hed->bsd", y, lp["wo"])), new_kv
 
 
 def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
@@ -632,7 +608,8 @@ def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
             (k.shape[0], k.shape[1]))
         y = attention(q, k, v, positions, epos, causal=False,
                       chunk=cfg.attn_chunk)
-        x = x + _reduce_barrier(jnp.einsum("bshe,hed->bsd", y, lp["xwo"]))
+        x = x + jax.lax.optimization_barrier(
+            jnp.einsum("bshe,hed->bsd", y, lp["xwo"]))
 
     if cfg.family != "ssm" or cfg.hybrid:
         h = rmsnorm(lp["ln2"], x, cfg.norm_eps)
@@ -687,7 +664,7 @@ def _embed_inputs(params, cfg: ArchConfig, batch):
                         params["frontend_proj"])
         x = jnp.concatenate([fe, x], axis=1)
     b, s, _ = x.shape
-    x = _constrain(_reduce_barrier(x), DP, None, None)
+    x = _constrain(jax.lax.optimization_barrier(x), DP, None, None)
     positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
     return x, positions
 

@@ -7,9 +7,9 @@ Three tools, all safe to leave wired in production code:
     tracer's null span when telemetry is off, so hot loops pay one global
     read when disabled;
   * :func:`profiler_trace` -- the opt-in ``jax.profiler.trace`` capture
-    (TensorBoard/XProf protos next to our own Chrome trace); failures to
-    start the native profiler (missing plugin, unsupported backend) degrade
-    to a no-op with an instant event instead of killing the run;
+    (TensorBoard/XProf protos next to our own Chrome trace); a trace that
+    was asked for and cannot start or stop raises, so a run never reports
+    a capture it does not have;
   * :class:`RecompileWatcher` -- tracks the ``jit`` cache size of registered
     functions and flags *unexpected* growth.  Silent retracing is the real
     footgun this repo has already been bitten by (the serving engines once
@@ -47,23 +47,19 @@ def annotation(name: str):
 
 @contextlib.contextmanager
 def profiler_trace(log_dir: Optional[str]):
-    """Opt-in native JAX profiler capture (no-op when ``log_dir`` is None)."""
+    """Opt-in native JAX profiler capture (no-op when ``log_dir`` is None).
+
+    Yields whether a trace is being captured.  Failing to start or stop a
+    requested trace raises.
+    """
     if log_dir is None:
         yield False
         return
-    try:
-        jax.profiler.start_trace(log_dir)
-    except Exception as e:                   # missing plugin / backend quirk
-        _trace.instant("jaxprof.unavailable", cat="jax", error=repr(e))
-        yield False
-        return
+    jax.profiler.start_trace(log_dir)
     try:
         yield True
     finally:
-        try:
-            jax.profiler.stop_trace()
-        except Exception as e:
-            _trace.instant("jaxprof.stop_failed", cat="jax", error=repr(e))
+        jax.profiler.stop_trace()
 
 
 def jit_cache_size(fn) -> Optional[int]:

@@ -120,6 +120,22 @@ def test_analytic_traffic_positive_all_cells():
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+
+def test_dryrun_never_takes_the_chip():
+    """The dry run pins itself to host devices even when the environment
+    asks for the TPU, so a launcher's dry-run child cannot hold the chip."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, PYTHONPATH=os.path.join(_REPO, "src"),
+               JAX_PLATFORMS="tpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", "import repro.launch.dryrun, jax; "
+         "print(jax.default_backend(), jax.device_count())"],
+        cwd=_REPO, env=env, capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    assert proc.stdout.split() == ["cpu", "512"]
+
 _POD_COMPRESS_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

@@ -279,6 +279,42 @@ class TestRecompileWatcher:
 
 
 # ---------------------------------------------------------------------------
+# profiler capture
+# ---------------------------------------------------------------------------
+
+class TestProfilerTrace:
+    def test_not_requested_is_a_no_op(self, monkeypatch):
+        def never(*a, **k):
+            raise AssertionError("profiler touched")
+        monkeypatch.setattr(jax.profiler, "start_trace", never)
+        with jaxprof.profiler_trace(None) as on:
+            assert on is False
+
+    def test_requested_trace_that_cannot_start_raises(self, tmp_path,
+                                                      monkeypatch):
+        def broken(log_dir, *a, **k):
+            raise RuntimeError("no profiler plugin")
+        monkeypatch.setattr(jax.profiler, "start_trace", broken)
+        with pytest.raises(RuntimeError, match="no profiler plugin"):
+            with jaxprof.profiler_trace(str(tmp_path)):
+                pytest.fail("body ran without a trace")
+
+    def test_requested_trace_that_cannot_stop_raises(self, tmp_path,
+                                                     monkeypatch):
+        calls = []
+        monkeypatch.setattr(jax.profiler, "start_trace",
+                            lambda log_dir, *a, **k: calls.append(log_dir))
+
+        def broken():
+            raise RuntimeError("stop failed")
+        monkeypatch.setattr(jax.profiler, "stop_trace", broken)
+        with pytest.raises(RuntimeError, match="stop failed"):
+            with jaxprof.profiler_trace(str(tmp_path)) as on:
+                assert on is True
+        assert calls == [str(tmp_path)]
+
+
+# ---------------------------------------------------------------------------
 # end-to-end: traced training and serving
 # ---------------------------------------------------------------------------
 

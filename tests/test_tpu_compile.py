@@ -1,0 +1,106 @@
+"""Compile the main path's TPU programs for a described v5e chip.
+
+Nothing runs: the TPU compiler, which is installed with JAX, compiles for a
+chip that is described and not attached.  This catches what interpret mode
+cannot (layouts Mosaic refuses, VMEM over-use, a kernel silently swapped for
+the jnp oracle) without a chip.  The topology is described only inside the
+module fixture below, so importing this file loads no TPU library and every
+test worker collects the same tests.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.compression.transform import MAX_WORDS, TOTAL_PLANES
+from repro.data import channels_last
+from repro.kernels import zfp_codec
+from repro.models.surrogate import SurrogateConfig, init_surrogate
+from repro.train import source
+from repro.train.optimizer import AdamConfig, adam_init
+
+# one training batch of the paper's RT surrogate: 64 samples x 6 fields x
+# (96/4)*(32/4) blocks
+BATCH = 64
+SAMPLE = (6, 96, 32)
+BLOCKS_PER_SAMPLE = 6 * (96 // 4) * (32 // 4)
+NB = BATCH * BLOCKS_PER_SAMPLE                   # 73,728
+RESIDENT_SAMPLES = 5100                          # 100 simulations x 51 snapshots
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")     # no compiler logs under /tmp
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # a compile for a described chip is written to the persistent cache
+        # but cannot be read back without the chip: keep the cache out of it
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+            compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_cases(sharding):
+    i32, f32 = jnp.int32, jnp.float32
+    payload = _spec((NB, MAX_WORDS), i32, sharding)
+    per_block = _spec((NB,), i32, sharding)
+    blocks = _spec((NB, 16), f32, sharding)
+    return {
+        "decode": lambda: zfp_codec.zfp_decode_blocks.lower(
+            payload, per_block, bits_per_value=TOTAL_PLANES),
+        "decode_fa": lambda: zfp_codec.zfp_decode_blocks_fa.lower(
+            payload, per_block, per_block),
+        "encode": lambda: zfp_codec.zfp_encode_blocks.lower(
+            blocks, bits_per_value=TOTAL_PLANES),
+        "encode_fa": lambda: zfp_codec.zfp_encode_blocks_fa.lower(
+            blocks, _spec((NB,), f32, sharding)),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["decode", "decode_fa", "encode",
+                                    "encode_fa"])
+def test_codec_kernel_compiles_for_v5e(one_chip, kernel):
+    compiled = _kernel_cases(one_chip)[kernel]().compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_train_step_compiles_with_decode_kernel(one_chip):
+    """The device-resident train step at the paper's widths carries the
+    compiled fixed-accuracy decode kernel, not the jnp oracle."""
+    cfg, opt_cfg = SurrogateConfig(), AdamConfig()
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = jax.eval_shape(lambda: init_surrogate(jax.random.PRNGKey(0), cfg))
+    opt_state = jax.eval_shape(lambda p: adam_init(p, opt_cfg), params)
+    n, nb = RESIDENT_SAMPLES, BLOCKS_PER_SAMPLE
+    lowered = source._fused_step.lower(
+        on_chip(params), on_chip(opt_state),
+        _spec((BATCH,), jnp.int32, one_chip),
+        _spec((n, nb, MAX_WORDS), jnp.int32, one_chip),
+        _spec((n, nb), jnp.int32, one_chip),
+        _spec((n, nb), jnp.int32, one_chip),
+        _spec((n, cfg.cond_dim), jnp.float32, one_chip),
+        cfg=cfg, opt_cfg=opt_cfg, padded_shape=SAMPLE, shape=SAMPLE,
+        transform=channels_last)
+    compiled = lowered.compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
