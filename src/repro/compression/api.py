@@ -38,6 +38,7 @@ from repro.compression.zfp import (
     encode_fixed_accuracy_batch, encode_fixed_rate_batch, fa_precompute_batch,
     fa_stats_batch, trim_to_nplanes,
 )
+from repro.obs import trace as obs_trace
 
 BACKENDS = ("jnp", "pallas")
 
@@ -142,14 +143,19 @@ class FixedAccuracyCodec:
         return "fixed_accuracy"
 
     def encode_batch(self, xs, tolerances=None) -> CompressedField:
+        """Encode ``xs`` at per-sample tolerances; the ``codec.encode_batch``
+        span times the host's side of it (the dispatch, or the trace under
+        ``jit``), not the device's."""
         if tolerances is None:
             if self.tolerance is None:
                 raise ValueError("fixed_accuracy encode needs per-sample "
                                  "tolerances or a codec-level default")
             tolerances = jnp.full((xs.shape[0],), self.tolerance, jnp.float32)
-        return encode_fixed_accuracy_batch(
-            xs, jnp.asarray(tolerances, jnp.float32),
-            use_pallas=self.backend == "pallas")
+        with obs_trace.span("codec.encode_batch", cat="codec",
+                            samples=int(xs.shape[0])):
+            return encode_fixed_accuracy_batch(
+                xs, jnp.asarray(tolerances, jnp.float32),
+                use_pallas=self.backend == "pallas")
 
     def decode_batch(self, cf: CompressedField) -> jnp.ndarray:
         if self.backend == "pallas":

@@ -238,11 +238,11 @@ def find_tolerance_batch(samples: np.ndarray | Sequence[np.ndarray],
     assert xs.shape[0] == es.shape[0], "one model error per sample"
     with obs_trace.span("tolerance.search_batch", cat="certify",
                         samples=int(xs.shape[0])) as sp:
-        tol, l1, ratio, iters = _search_batch(
-            xs, es, d, max_iters,
-            _SEARCH_CODEC if codec is None else codec, fused)
-        iters = np.asarray(iters)
+        found = _search_batch(xs, es, d, max_iters,
+                              _SEARCH_CODEC if codec is None else codec,
+                              fused)
+        # the host waits here for the whole search to finish on the device
+        with obs_trace.span("tolerance.readback", cat="certify"):
+            tol, l1, ratio, iters = (np.asarray(a) for a in found)
         sp.set(max_iterations=int(iters.max(initial=0)))
-    return BatchToleranceResult(np.asarray(tol), np.asarray(es),
-                                np.asarray(l1), np.asarray(ratio),
-                                iters)
+    return BatchToleranceResult(tol, np.asarray(es), l1, ratio, iters)

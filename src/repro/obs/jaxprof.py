@@ -1,11 +1,8 @@
 """JAX-side observability: scopes, profiler capture, recompile detection.
 
-Three tools, all safe to leave wired in production code:
+Two tools, both safe to leave wired in production code (host-side region
+markers are ``obs.trace.span``, which is also a profiler annotation):
 
-  * :func:`annotation` -- a ``jax.profiler.TraceAnnotation`` (host-side
-    region marker the XLA profiler timeline picks up) that degrades to the
-    tracer's null span when telemetry is off, so hot loops pay one global
-    read when disabled;
   * :func:`profiler_trace` -- the opt-in ``jax.profiler.trace`` capture
     (TensorBoard/XProf protos next to our own Chrome trace); a trace that
     was asked for and cannot start or stop raises, so a run never reports
@@ -36,13 +33,6 @@ from repro.obs import trace as _trace
 from repro.obs.metrics import MetricsRegistry, get_registry
 
 named_scope = jax.named_scope
-
-
-def annotation(name: str):
-    """Profiler region marker; null when telemetry is off."""
-    if not _trace.enabled():
-        return _trace.NULL_SPAN
-    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager

@@ -1,7 +1,7 @@
 """Metrics registry: counters, gauges, windowed histograms -- and IoStats.
 
 The observability layer's aggregate half.  A ``MetricsRegistry`` holds named
-instruments, all thread-safe, all zero-dependency:
+instruments, all thread-safe, stdlib only:
 
   * ``Counter``   -- monotonically accumulating value (``add``);
   * ``Gauge``     -- last-written value (``set``), e.g. compile seconds;

@@ -1,4 +1,5 @@
-"""Lightweight thread-safe span tracer: Perfetto/Chrome traces + JSONL events.
+"""Thread-safe span tracer: Perfetto/Chrome traces + JSONL events, and the
+same spans on the JAX profiler's timeline.
 
 The observability layer's timeline half.  A ``Tracer`` records *spans*
 (named, nested, attributed intervals), *instants* (point events) and
@@ -12,15 +13,23 @@ timeline.  Export is dual:
   * ``<run>.events.jsonl`` -- one structured JSON event per line (seconds,
     depth, attrs), the stream ``tools/trace_report.py`` summarizes.
 
+One span call reaches both timelines: every span also opens a
+``jax.profiler.TraceAnnotation`` of its name and attributes, so while a JAX
+profiler capture runs -- ``obs.jaxprof.profiler_trace``, a
+``jax.profiler.start_trace`` from outside, or a remote capture -- the span
+sits on the capture's host plane, on the same clock as the device's ops.
+
 Design constraints (the hot paths this instruments are per-train-step and
 per-decode-step):
 
   * **off by default, near-zero when off** -- the module-level ``span()`` /
-    ``instant()`` / ``counter()`` helpers check one global and return a
-    shared no-op context manager when no tracer is configured; no clock is
-    read, no object is allocated;
-  * **zero dependencies** -- stdlib only, importable from any layer
-    (``tools/check_layering.py`` ranks ``obs`` at the bottom of the ladder);
+    ``instant()`` / ``counter()`` helpers check one global and, for
+    ``span()``, whether a profiler capture is recording; with neither they
+    return a shared no-op context manager: no clock is read, no object is
+    allocated.  With a capture and no tracer, a span is the annotation alone;
+  * **one dependency** -- the stdlib and ``jax.profiler``, importable from
+    any layer (``tools/check_layering.py`` ranks ``obs`` at the bottom of the
+    ladder; it imports nothing else of ``repro``);
   * **thread-safe** -- per-thread span stacks via ``threading.local``, one
     lock around the shared event list;
   * **bounded** -- at most ``max_events`` events are retained; overflow is
@@ -34,6 +43,12 @@ import os
 import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+# whether a profiler capture is recording (a static check, ~0.05 us): a
+# ``TraceAnnotation`` opened while none is records nothing
+_capturing = TraceAnnotation.is_enabled
 
 
 class _NullSpan:
@@ -53,8 +68,19 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class _Annotation(TraceAnnotation):
+    """A span on the profiler's timeline only: what ``span()`` returns while
+    a capture records and no tracer is configured.  Like any
+    ``TraceAnnotation`` it starts when made, so open it where it is made."""
+    __slots__ = ()
+
+    def set(self, **attrs) -> "_Annotation":
+        self.set_metadata(**attrs)
+        return self
+
+
 class _Span:
-    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth")
+    __slots__ = ("_tracer", "name", "cat", "attrs", "_t0", "_depth", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, attrs: dict):
         self._tracer = tracer
@@ -65,17 +91,20 @@ class _Span:
     def set(self, **attrs) -> "_Span":
         """Attach attributes discovered mid-span (e.g. iteration counts)."""
         self.attrs.update(attrs)
+        self._ann.set_metadata(**attrs)
         return self
 
     def __enter__(self):
         stack = self._tracer._stack()
         self._depth = len(stack)
         stack.append(self)
+        self._ann = TraceAnnotation(self.name, **self.attrs)
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         dur = time.perf_counter() - self._t0
+        self._ann.__exit__(exc_type, exc, tb)
         self._tracer._stack().pop()
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
@@ -124,7 +153,8 @@ class Tracer:
             self._events.append(rec)
 
     def span(self, name: str, cat: str = "span", **attrs) -> _Span:
-        """Context manager timing a nested, attributed interval."""
+        """Context manager timing a nested, attributed interval; it is also
+        a profiler annotation of the same name and attributes."""
         return _Span(self, name, cat, attrs)
 
     def complete(self, name: str, start: float, dur: float, cat: str = "span",
@@ -230,11 +260,15 @@ def shutdown(write: bool = True) -> Optional[dict]:
 
 
 def span(name: str, cat: str = "span", **attrs):
-    """Global-tracer span; the shared no-op when telemetry is off."""
+    """Global-tracer span, also on the profiler's timeline; a profiler
+    annotation alone while a capture records and no tracer is configured;
+    the shared no-op when neither is on."""
     t = _TRACER
-    if t is None:
-        return NULL_SPAN
-    return t.span(name, cat, **attrs)
+    if t is not None:
+        return t.span(name, cat, **attrs)
+    if _capturing():
+        return _Annotation(name, **attrs)
+    return NULL_SPAN
 
 
 def instant(name: str, cat: str = "event", **attrs) -> None:

@@ -21,9 +21,21 @@ uploaded per step.
 Continuous batching comes from the shared ``SlotScheduler``: rollouts of
 mixed lengths retire independently and freed slots are refilled mid-flight,
 vs the ``run_lockstep`` baseline that drains ``max(T)`` steps per chunk.
+
+Each pass of ``run``'s loop is split into phases that partition its wall
+time: ``no_work`` (no slot active: sleep until the next arrival),
+``dispatch`` (cond upload and fleet-step enqueue), ``device_wait`` (until
+the step's mean and band are ready), ``fetch`` (their device-to-host copy)
+and ``collect`` (admission, cond rows, per-slot appends, finished rollouts'
+stacking, slot refill).  Each is a ``surrogate_serve.<phase>`` span --
+``dispatch``, ``device_wait`` and ``fetch`` inside one
+``surrogate_serve.fleet_step`` -- and a cumulative
+``surrogate_serve.<phase>_seconds`` counter in the global registry, always
+on.  The device idles in every phase but ``device_wait``.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -52,6 +64,29 @@ class SurrogateQuery:
     @property
     def steps(self) -> int:
         return int(np.asarray(self.times).shape[0])
+
+
+PHASES = ("no_work", "dispatch", "device_wait", "fetch", "collect")
+
+
+class _PhaseClock:
+    """Cumulative seconds per phase of ``run``'s loop on one chained clock:
+    a phase is charged from the end of the phase before it to its own end,
+    so the phases partition the loop's wall time."""
+
+    def __init__(self, t0: float):
+        reg = obs_metrics.get_registry()
+        self._counters = {p: reg.counter(f"surrogate_serve.{p}_seconds")
+                          for p in PHASES}
+        self._mark = t0
+
+    @contextlib.contextmanager
+    def __call__(self, phase: str):
+        with obs_trace.span("surrogate_serve." + phase, cat="serve"):
+            yield
+        now = time.perf_counter()
+        self._counters[phase].add(now - self._mark)
+        self._mark = now
 
 
 @partial(jax.jit, static_argnames=("cfg", "sigmas"))
@@ -90,9 +125,12 @@ class SurrogateServeEngine:
 
     # -- internals ----------------------------------------------------------
 
+    def _dispatch(self, cond_np: np.ndarray):
+        return _fleet_step(self.members, jnp.asarray(cond_np), self.cfg,
+                           self.sigmas)
+
     def _step(self, cond_np: np.ndarray):
-        mean, width = _fleet_step(self.members, jnp.asarray(cond_np),
-                                  self.cfg, self.sigmas)
+        mean, width = self._dispatch(cond_np)
         return np.asarray(mean), np.asarray(width)
 
     def _finish(self, q: SurrogateQuery, means: list, widths: list,
@@ -140,6 +178,7 @@ class SurrogateServeEngine:
         t_start = time.perf_counter()
         clock = lambda: time.perf_counter() - t_start
         self._t_run_start = t_start
+        phase = _PhaseClock(t_start)
         reg = obs_metrics.get_registry()
         occ_hist = reg.histogram("surrogate_serve.slot_occupancy")
         tracer = obs_trace.get_tracer()
@@ -150,59 +189,68 @@ class SurrogateServeEngine:
         first_step = True
 
         while not sched.done:
-            now = clock()
-            while True:
-                adm = sched.admit(now)
-                if not adm:
-                    break
-                recycled = False
-                for slot, q in adm:
-                    q._seated = now
-                    if q.steps == 0:         # empty rollout: return as-is
-                        self._finish(q, [], [], clock(), done)
-                        sched.complete(slot)
-                        recycled = True
-                    else:
-                        step_idx[slot] = 0
-                        means[slot], widths[slot] = [], []
-                        cond[slot] = self._cond_row(q, 0)
-                if not recycled:
-                    break
+            with phase("collect"):
+                now = clock()
+                while True:
+                    adm = sched.admit(now)
+                    if not adm:
+                        break
+                    recycled = False
+                    for slot, q in adm:
+                        q._seated = now
+                        if q.steps == 0:     # empty rollout: return as-is
+                            self._finish(q, [], [], clock(), done)
+                            sched.complete(slot)
+                            recycled = True
+                        else:
+                            step_idx[slot] = 0
+                            means[slot], widths[slot] = [], []
+                            cond[slot] = self._cond_row(q, 0)
+                    if not recycled:
+                        break
+                active = sched.active_items()
 
-            active = sched.active_items()
             if not active:
-                nxt_arr = sched.next_arrival()
-                if nxt_arr is not None and nxt_arr > clock():
-                    time.sleep(min(nxt_arr - clock(), 0.005))
+                with phase("no_work"):
+                    nxt_arr = sched.next_arrival()
+                    if nxt_arr is not None and nxt_arr > clock():
+                        time.sleep(min(nxt_arr - clock(), 0.005))
                 continue
 
             t0 = time.perf_counter()
-            mean_b, width_b = self._step(cond)
-            step_s = time.perf_counter() - t0
-            self.stats["seconds"] += step_s
+            with obs_trace.span("surrogate_serve.fleet_step", cat="serve",
+                                active=len(active), members=self.num_members):
+                with phase("dispatch"):
+                    mean, width = self._dispatch(cond)
+                with phase("device_wait"):
+                    jax.block_until_ready((mean, width))
+                with phase("fetch"):
+                    mean_b, width_b = np.asarray(mean), np.asarray(width)
+                    # free the device buffers before the next step's
+                    # outputs are allocated, as ``_step`` does
+                    del mean, width
+            self.stats["seconds"] += time.perf_counter() - t0
             self.stats["steps"] += 1
             self.stats["field_evals"] += len(active)
-            occ_hist.observe(len(active) / b)
-            if first_step:
-                first_step = False
-                watcher.rebase()        # first-step compile is expected
-            if tracer is not None:
-                tracer.complete("surrogate_serve.fleet_step", tracer.rel(t0),
-                                step_s, cat="serve", active=len(active),
-                                members=self.num_members)
-                tracer.counter("surrogate_serve.slots", active=len(active),
-                               total=b)
-            now = clock()
-            for slot, q in active:
-                means[slot].append(mean_b[slot])
-                widths[slot].append(width_b[slot])
-                k = int(step_idx[slot]) + 1
-                if k >= q.steps:
-                    self._finish(q, means[slot], widths[slot], now, done)
-                    sched.complete(slot)
-                else:
-                    step_idx[slot] = k
-                    cond[slot] = self._cond_row(q, k)
+            with phase("collect"):
+                occ_hist.observe(len(active) / b)
+                if first_step:
+                    first_step = False
+                    watcher.rebase()        # first-step compile is expected
+                if tracer is not None:
+                    tracer.counter("surrogate_serve.slots",
+                                   active=len(active), total=b)
+                now = clock()
+                for slot, q in active:
+                    means[slot].append(mean_b[slot])
+                    widths[slot].append(width_b[slot])
+                    k = int(step_idx[slot]) + 1
+                    if k >= q.steps:
+                        self._finish(q, means[slot], widths[slot], now, done)
+                        sched.complete(slot)
+                    else:
+                        step_idx[slot] = k
+                        cond[slot] = self._cond_row(q, k)
         watcher.check()         # flags mid-run fleet-step recompiles
         return done
 

@@ -177,24 +177,42 @@ def train_surrogate(model_cfg: SurrogateConfig, train_cfg: TrainConfig,
 
     # -- telemetry: compile vs steady-state split, recompile watch ----------
     # The first step of a run pays jit compilation; folding it into the
-    # per-step rate skews every log_every-window throughput number (the bug
-    # this split fixes).  ``train.compile_seconds`` is reported once; the
-    # steady-state counters/histogram and the per-window events exclude it.
+    # per-step rate skews every log_every-window throughput number.
+    # ``train.compile_seconds`` is reported once; the steady-state
+    # counters/histogram and the per-window events exclude it.  A step call
+    # returns once the step is enqueued, so steps are timed by the window:
+    # the wall time from one log boundary to the next, each ending in the
+    # ``float(loss)`` read that waits for the window's last step (and the
+    # end of the run in one wait for its trailing steps).
     from repro.train import source as source_mod
     reg = obs_metrics.get_registry()
     watcher = jaxprof.get_watcher()
     watcher.watch("train.fused_step" if device_path else "train.step",
                   source_mod._fused_step if device_path else _train_step)
     step_hist = reg.histogram("train.step_seconds")
-    tracer = obs_trace.get_tracer()
     first_in_run = True
     steady_s = 0.0
-    win_steps, win_s = 0, 0.0
+    win_steps, win_t0 = 0, 0.0
     start_step = step
+
+    def close_window() -> None:
+        """Charge the steps since the last boundary, now complete."""
+        nonlocal steady_s, win_steps, win_t0
+        now = time.perf_counter()
+        if win_steps:
+            dur = now - win_t0
+            for _ in range(win_steps):
+                step_hist.observe(dur / win_steps)
+            steady_s += dur
+            obs_trace.instant("train.window", cat="train", step=step,
+                              steps=win_steps, seconds=dur,
+                              steps_per_s=win_steps / max(dur, 1e-9))
+        win_steps, win_t0 = 0, now
 
     stream = batch_stream(loader, source.fetch, train_cfg.epochs, prefetch)
     losses = []
     saved_step = -1
+    preempted = False
     try:
         t_iter = time.perf_counter()
         for lstate, item in stream:
@@ -221,24 +239,13 @@ def train_surrogate(model_cfg: SurrogateConfig, train_cfg: TrainConfig,
                 obs_trace.instant("train.compile", cat="train", step=step,
                                   seconds=compile_s)
                 watcher.rebase()        # first-step compiles are expected
-                dur = compile_s
+                close_window()          # steady windows start here
             else:
-                dur = time.perf_counter() - t0s
-                steady_s += dur
-                step_hist.observe(dur)
                 win_steps += 1
-                win_s += dur
-            if tracer is not None:
-                tracer.complete("train.step", tracer.rel(t0s), dur,
-                                cat="train", step=step)
             last_state = lstate
             if step % train_cfg.log_every == 0:
                 losses.append((step, float(loss)))
-                if win_steps:           # steady-state only: compile excluded
-                    obs_trace.instant(
-                        "train.window", cat="train", step=step,
-                        steps_per_s=win_steps / max(win_s, 1e-9))
-                win_steps, win_s = 0, 0.0
+                close_window()          # steady-state only: compile excluded
             if hooks:
                 for h in hooks:
                     h(step, params, float(loss))
@@ -249,13 +256,19 @@ def train_surrogate(model_cfg: SurrogateConfig, train_cfg: TrainConfig,
                           params_prev)
                 saved_step = step
             if train_cfg.max_steps is not None and step >= train_cfg.max_steps:
-                return params, losses   # preempted: no final save
+                preempted = True
+                break
             t_iter = time.perf_counter()
+        if win_steps:
+            jax.block_until_ready(loss)
+            close_window()
     finally:
         stream.close()
         reg.counter("train.steps").add(step - start_step)
         reg.counter("train.steady_seconds").add(steady_s)
         watcher.check()     # flags (event + counter) steady-state recompiles
+    if preempted:
+        return params, losses   # no final save
     if train_cfg.ckpt_dir and step != saved_step:
         _save(train_cfg, step, params, opt_state, last_state, params_prev)
     return params, losses

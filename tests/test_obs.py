@@ -5,6 +5,8 @@ Covers the obs contracts the rest of the repo now leans on:
   * Chrome trace-event export is schema-valid (Perfetto-loadable) and the
     JSONL stream parses line by line;
   * disabled mode is the shared null object -- no allocation, no clock read;
+  * one span call reaches both timelines: the tracer's events and the host
+    plane of a running JAX profiler capture, with or without a tracer;
   * metrics merge/snapshot round-trips; ``IoStats`` is ONE class (the
     ``repro.data.store`` import is a re-export) with the historical
     attribute API intact;
@@ -12,13 +14,16 @@ Covers the obs contracts the rest of the repo now leans on:
     quiet in steady state;
   * end-to-end: a traced ``train_surrogate`` run separates compile from
     steady-state and emits per-step spans; a traced serving run emits
-    per-query spans + slot-occupancy samples; ``tools/trace_report``
+    per-query spans + slot-occupancy samples, and its loop phases partition
+    the run's wall time on both timelines; ``tools/trace_report``
     summarizes the stream into a per-stage table.
 """
+import glob
 import json
 import os
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -314,6 +319,85 @@ class TestProfilerTrace:
         assert calls == [str(tmp_path)]
 
 
+def host_events(log_dir) -> list:
+    """``(name, line, start_ns, end_ns, stats)`` of every event on the host
+    planes of the capture under ``log_dir``."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    out.append((ev.name, line.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns,
+                                {k: v for k, v in ev.stats}))
+    return out
+
+
+def inside(child, parents) -> bool:
+    """Whether ``child`` lies within one of ``parents`` on its line."""
+    return any(p[1] == child[1] and p[2] <= child[2] and child[3] <= p[3]
+               for p in parents)
+
+
+class TestSpansOnTheProfiler:
+    def test_span_without_tracer_is_an_annotation(self, tmp_path,
+                                                  clean_telemetry):
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with obs_trace.span("obs.outer", cat="x", k=3) as sp:
+                assert sp is not NULL_SPAN
+                sp.set(found=7)
+                with obs_trace.span("obs.inner"):
+                    time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        assert obs_trace.span("after") is NULL_SPAN   # capture over
+        evs = {e[0]: e for e in host_events(tmp_path)}
+        assert evs["obs.outer"][4] == {"k": 3, "found": 7}
+        assert inside(evs["obs.inner"], [evs["obs.outer"]])
+        assert evs["obs.inner"][3] - evs["obs.inner"][2] >= 2e6
+
+    def test_tracer_span_reaches_both_sinks(self, tmp_path, clean_telemetry):
+        tracer = obs_trace.configure(None, run="both")
+        jax.profiler.start_trace(str(tmp_path / "xp"))
+        try:
+            with obs_trace.span("obs.both", step=2) as sp:
+                sp.set(done=True)
+        finally:
+            jax.profiler.stop_trace()
+        (ev,) = tracer.events()
+        assert ev["name"] == "obs.both"
+        assert ev["args"] == {"step": 2, "done": True}
+        (xp,) = [e for e in host_events(tmp_path / "xp")
+                 if e[0] == "obs.both"]
+        assert xp[4]["step"] == 2
+        # both sinks time the same interval
+        assert abs((xp[3] - xp[2]) * 1e-9 - ev["dur"]) < 1e-3
+
+    def test_certify_spans_nest(self, clean_telemetry):
+        from repro.compression import get_codec
+        from repro.core import find_tolerance_batch
+
+        tracer = obs_trace.configure(None, run="certify")
+        xs = np.random.default_rng(0).normal(size=(2, 8, 8)).astype(
+            np.float32)
+        res = find_tolerance_batch(xs, np.full(2, 0.02, np.float32))
+        get_codec("fixed_accuracy", backend="jnp").encode_batch(
+            jnp.asarray(xs), jnp.asarray(res.tolerance))
+        evs = {e["name"]: e for e in tracer.events()}
+        search, readback = evs["tolerance.search_batch"], evs[
+            "tolerance.readback"]
+        assert readback["depth"] == search["depth"] + 1
+        assert search["ts"] <= readback["ts"]
+        assert (readback["ts"] + readback["dur"]
+                <= search["ts"] + search["dur"] + 1e-9)
+        assert search["args"]["max_iterations"] >= 1
+        assert evs["codec.encode_batch"]["args"]["samples"] == 2
+
+
 # ---------------------------------------------------------------------------
 # end-to-end: traced training and serving
 # ---------------------------------------------------------------------------
@@ -341,17 +425,24 @@ class TestEndToEnd:
         assert (snap["train.step_seconds"]["max"]
                 < snap["train.compile_seconds"])
         assert snap["train.steady_seconds"] > 0
+        # steps are timed whole, window by window: the histogram sums to
+        # the steady seconds, and the windows cover every steady step
+        assert (abs(snap["train.step_seconds"]["mean"] * 7
+                    - snap["train.steady_seconds"]) < 1e-9)
 
         evs = obs_trace.get_tracer().events()
-        steps = [e for e in evs if e["name"] == "train.step"]
-        assert len(steps) == 8
         assert sum(1 for e in evs if e["name"] == "train.compile") == 1
         windows = [e for e in evs if e["name"] == "train.window"]
-        assert windows and all(
-            e["args"]["steps_per_s"] > 0 for e in windows)
+        assert [e["args"]["step"] for e in windows] == [2, 4, 6, 8]
+        assert sum(e["args"]["steps"] for e in windows) == 7
+        assert (abs(sum(e["args"]["seconds"] for e in windows)
+                    - snap["train.steady_seconds"]) < 1e-9)
+        assert all(e["args"]["steps_per_s"] > 0 and abs(
+            e["args"]["steps_per_s"] * e["args"]["seconds"]
+            - e["args"]["steps"]) < 1e-6 for e in windows)
         fetches = [e for e in evs if e["name"] == "train.fetch"]
         assert fetches                          # prefetch worker traced
-        assert {e["tid"] for e in fetches} != {steps[0]["tid"]}
+        assert {e["tid"] for e in fetches} != {windows[0]["tid"]}
 
     def test_surrogate_serving_telemetry(self, tmp_path, clean_telemetry):
         from repro.core.ensemble import init_ensemble
@@ -381,6 +472,81 @@ class TestEndToEnd:
         assert len(reqs) == 3
         assert all(e["args"]["queue_wait_s"] >= 0 for e in reqs)
         assert [e for e in evs if e["ph"] == "C"]   # occupancy counter track
+
+    @staticmethod
+    def _tiny_engine():
+        from repro.core.ensemble import init_ensemble
+        from repro.models.surrogate import SurrogateConfig
+        from repro.serving import SurrogateQuery, SurrogateServeEngine
+
+        cfg = SurrogateConfig(height=16, width=8, base_channels=8)
+        engine = SurrogateServeEngine(init_ensemble(cfg, [0, 1]), cfg,
+                                      batch_slots=2)
+
+        def queries():
+            return [SurrogateQuery(np.zeros(cfg.cond_dim - 1, np.float32),
+                                   np.linspace(0, 1, t).astype(np.float32),
+                                   arrival=0.03 + 0.02 * i)
+                    for i, t in enumerate((2, 3, 1, 4))]
+        engine.run(queries())                   # compiles the fleet step
+        return engine, queries
+
+    def test_serve_phases_on_the_profiler_host_plane(self, tmp_path,
+                                                     clean_telemetry):
+        engine, queries = self._tiny_engine()
+        steps = engine.stats["steps"]
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            engine.run(queries())
+        finally:
+            jax.profiler.stop_trace()
+        steps = engine.stats["steps"] - steps
+        evs = host_events(tmp_path)
+        by = {p: [e for e in evs if e[0] == "surrogate_serve." + p]
+              for p in ("fleet_step", "dispatch", "device_wait", "fetch",
+                        "collect", "no_work")}
+        assert len(by["fleet_step"]) == steps
+        assert all(e[4]["members"] == 2 and 1 <= e[4]["active"] <= 2
+                   for e in by["fleet_step"])
+        for p in ("dispatch", "device_wait", "fetch"):
+            assert len(by[p]) == steps
+            assert all(inside(e, by["fleet_step"]) for e in by[p])
+        assert by["collect"] and by["no_work"]
+        assert not any(inside(e, by["fleet_step"])
+                       for e in by["collect"] + by["no_work"])
+
+    def test_serve_phases_in_the_tracer(self, tmp_path, clean_telemetry):
+        engine, queries = self._tiny_engine()
+        obs_trace.configure(str(tmp_path), run="phases")
+        steps = engine.stats["steps"]
+        engine.run(queries())
+        steps = engine.stats["steps"] - steps
+        evs = obs_trace.get_tracer().events()
+        names = [e["name"] for e in evs]
+        for p in ("fleet_step", "dispatch", "device_wait", "fetch"):
+            assert names.count("surrogate_serve." + p) == steps
+        assert "surrogate_serve.collect" in names
+        step = next(e for e in evs if e["name"] == "surrogate_serve.fleet_step")
+        assert step["args"]["members"] == 2 and step["depth"] == 0
+        assert all(e["depth"] == 1 for e in evs if e["name"] in (
+            "surrogate_serve.dispatch", "surrogate_serve.device_wait",
+            "surrogate_serve.fetch"))
+
+    def test_serve_phase_counters_partition_the_run(self, clean_telemetry):
+        from repro.serving.surrogate_engine import PHASES
+
+        engine, queries = self._tiny_engine()
+        reg = obs_metrics.get_registry()
+
+        def phase_seconds():
+            snap = reg.snapshot()
+            return sum(snap[f"surrogate_serve.{p}_seconds"] for p in PHASES)
+        before = phase_seconds()
+        t0 = time.perf_counter()
+        engine.run(queries())
+        wall = time.perf_counter() - t0
+        grown = phase_seconds() - before
+        assert 0.9 * wall <= grown <= wall
 
     def test_trace_report_summarizes(self, tmp_path, clean_telemetry):
         import trace_report

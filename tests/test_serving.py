@@ -304,6 +304,40 @@ def test_surrogate_continuous_matches_lockstep(fleet):
     assert cont.slot_utilization >= lock.slot_utilization
 
 
+def test_surrogate_frees_each_step_outputs_before_the_next(fleet,
+                                                         monkeypatch):
+    """No fleet step's device outputs outlive their copy to the host: the
+    next step's outputs never share the device with them (peak memory)."""
+    import weakref
+    from repro.serving import surrogate_engine
+
+    class CopyingNumpy:
+        """numpy whose ``asarray`` copies, as the fetch from a chip does
+        (on the CPU it is a view that keeps the device array alive)."""
+        def __getattr__(self, name):
+            return getattr(np, name)
+
+        @staticmethod
+        def asarray(a, *args, **kwargs):
+            return np.array(a, *args, copy=True, **kwargs)
+    monkeypatch.setattr(surrogate_engine, "np", CopyingNumpy())
+    cfg, members = fleet
+    eng = SurrogateServeEngine(members, cfg, batch_slots=2)
+    held, live_at_dispatch = [], []
+    dispatch = eng._dispatch
+
+    def tracked(cond):
+        live_at_dispatch.append(sum(r() is not None for r in held))
+        out = dispatch(cond)
+        held.extend(weakref.ref(a) for a in out)
+        return out
+    eng._dispatch = tracked
+    eng.run(surrogate_workload(cfg.cond_dim - 1, 3, rollout_lens=(2, 4),
+                               seed=3))
+    assert len(live_at_dispatch) == eng.stats["steps"] > 1
+    assert live_at_dispatch == [0] * len(live_at_dispatch)
+
+
 def test_surrogate_requires_stacked_members(fleet):
     cfg, members = fleet
     with pytest.raises(ValueError, match="stacked"):

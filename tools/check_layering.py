@@ -12,7 +12,7 @@ Two rules, enforced over every module in ``src/repro`` by AST inspection
 
    ``obs`` (telemetry: span tracer, metrics registry, JAX profiling hooks)
    is the ladder's bottom rung: every layer may import it, and it imports
-   nothing from ``repro`` at all.
+   nothing from ``repro`` at all (only the stdlib and ``jax``).
 
    Function-local (lazy) imports are the sanctioned escape hatch for the
    few documented back-edges -- compression -> kernels (backend dispatch),
@@ -43,7 +43,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(REPO, "src", "repro")
 
 LAYER_RANK = {
-    "obs": 0,                    # telemetry: zero-dep, importable anywhere
+    "obs": 0,                    # telemetry: stdlib + jax, importable anywhere
     "configs": 1,
     "compression": 2,
     "kernels": 3,
