@@ -52,6 +52,21 @@ def blockify(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(-1, 16)
 
 
+def blockify_coef_major(x: jnp.ndarray) -> jnp.ndarray:
+    """(..., H, W) -> (16, nb): ``blockify(x).T``.
+
+    Row k = 4r + c holds coefficient (r, c) of every block, blocks in
+    ``blockify``'s order, so a kernel can lay the blocks along its lanes.
+    Columns c move out first, then rows r: taken in one transpose, XLA on
+    the TPU materialises the (..., 4) split with its minor 4 padded to 128
+    lanes, 32 times the field's bytes.
+    """
+    *lead, h, w = x.shape
+    x = jnp.moveaxis(x.reshape(-1, h, w // 4, 4), -1, 0)     # (4c, n, H, W/4)
+    x = x.reshape(4, -1, h // 4, 4, w // 4)
+    return jnp.transpose(x, (3, 0, 1, 2, 4)).reshape(16, -1)
+
+
 def deblockify(blocks: jnp.ndarray, shape) -> jnp.ndarray:
     """(nb, 16) -> (..., H, W), inverse of :func:`blockify`."""
     *lead, h, w = shape

@@ -190,8 +190,10 @@ def encode_fixed_accuracy_batch(xs: jnp.ndarray, tols: jnp.ndarray,
     ``use_pallas=True`` routes the whole per-block pipeline (quantize →
     lift → negabinary → plane guess → bound-verification correction →
     variable-plane pack) through the Pallas fixed-accuracy encode kernel
-    (``kernels/zfp_codec.py``; compiled-jnp oracle off-TPU): all N samples'
-    blocks are flattened into one (N*nb, 16) grid.  Both paths emit
+    (``kernels/zfp_codec.py``; compiled-jnp oracle off-TPU).  The padded
+    stack goes to it coefficient-major, (16, N*nb) from one transpose
+    (``T.blockify_coef_major``), so the kernel lays the blocks along its
+    128 lanes; no (nb, 16) array is built on this path.  Both paths emit
     bit-identical (payload, emax, nplanes) — the static in-VMEM correction
     loop is iteration-for-iteration the same arithmetic as the while_loop
     above (asserted in tests/test_compression.py and tests/test_kernels.py).
@@ -202,10 +204,10 @@ def encode_fixed_accuracy_batch(xs: jnp.ndarray, tols: jnp.ndarray,
     from repro.kernels import ops                    # lazy: ops imports zfp
     n = xs.shape[0]
     xp = T.pad_to_blocks(xs.astype(jnp.float32))
-    blocks = T.blockify(xp)                          # (N * nb, 16)
-    nb = blocks.shape[0] // n
-    payload, emax, nplanes = ops.zfp_encode_blocks_fa_fast(
-        blocks, jnp.repeat(tols, nb))
+    coefs = T.blockify_coef_major(xp)                # (16, N * nb)
+    nb = coefs.shape[1] // n
+    payload, emax, nplanes = ops.zfp_encode_coefs_fa_fast(
+        coefs, jnp.repeat(tols, nb))
     return CompressedField(payload.reshape(n, nb, -1), emax.reshape(n, nb),
                            nplanes.reshape(n, nb), xs.shape[1:], xp.shape[1:])
 

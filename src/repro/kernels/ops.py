@@ -90,27 +90,35 @@ def _ref_encode_jit(blocks, bits_per_value):
 
 
 def zfp_encode_blocks_fa(blocks, tols):
-    """Fixed-accuracy encode (per-block L-inf tolerances), kernel path."""
+    """Fixed-accuracy encode of (nb, 16) blocks (per-block L-inf
+    tolerances), kernel path."""
     return _kernel_on_tpu(
         zfp_codec.zfp_encode_blocks_fa,
         partial(zfp_codec.zfp_encode_blocks_fa, interpret=True),
-        blocks, tols)
+        blocks.T, tols)
 
 
 def zfp_encode_blocks_fa_fast(blocks, tols):
-    """Fixed-accuracy encode for throughput: kernel on TPU, oracle elsewhere.
+    """Fixed-accuracy encode of (nb, 16) blocks for throughput: kernel on
+    TPU, oracle elsewhere (``zfp_encode_coefs_fa_fast`` of ``blocks.T``)."""
+    return zfp_encode_coefs_fa_fast(blocks.T, tols)
+
+
+def zfp_encode_coefs_fa_fast(coefs, tols):
+    """Fixed-accuracy encode of coefficient-major (16, nb) blocks
+    (``transform.blockify_coef_major``): kernel on TPU, oracle elsewhere.
 
     Bit-identical to the kernel path (tests assert payload/emax/nplanes
     equality), so the codec seam's ``backend="pallas"`` encode and the
     datagen encode-on-device path use it unconditionally.
     """
     return _kernel_on_tpu(zfp_codec.zfp_encode_blocks_fa, _ref_encode_fa_jit,
-                          blocks, tols)
+                          coefs, tols)
 
 
 @jax.jit
-def _ref_encode_fa_jit(blocks, tols):
-    return ref.zfp_encode_blocks_fa_ref(blocks, tols)
+def _ref_encode_fa_jit(coefs, tols):
+    return ref.zfp_encode_blocks_fa_ref(coefs.T, tols)
 
 
 def decode_field(cf: CompressedField) -> jnp.ndarray:

@@ -33,6 +33,13 @@ def test_blockify_roundtrip(rng):
     assert np.allclose(deblockify(b, (3, 8, 12)), x)
 
 
+@pytest.mark.parametrize("shape", [(8, 12), (3, 16, 4), (2, 3, 12, 20)])
+def test_blockify_coef_major_is_blockify_transposed(rng, shape):
+    x = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
+    assert np.array_equal(np.asarray(T.blockify_coef_major(x)),
+                          np.asarray(blockify(x).T))
+
+
 def test_negabinary_roundtrip(rng):
     i = jnp.asarray(rng.integers(-2**29, 2**29, 100000).astype(np.int32))
     assert np.array_equal(T.nb2int(T.int2nb(i)), i)

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.compression import transform as T
-from repro.kernels import ops, ref
+from repro.kernels import ops, ref, zfp_codec
 
 
 def _blocks_from(rng, n_blocks, kind="smooth"):
@@ -130,6 +130,97 @@ def test_encode_decode_field_roundtrip(rng):
     out = ops.decode_field(cf)
     assert out.shape == x.shape
     assert float(jnp.max(jnp.abs(out - x))) < 1e-3
+
+
+# ---------------------------------------------------------------------------
+# coefficient-major fixed-accuracy encode kernel: bit identity with the oracle
+# ---------------------------------------------------------------------------
+
+FA_TILE = zfp_codec.FA_TILE_ROWS * 128          # blocks per grid step
+
+
+def _assert_fa_encode_matches_ref(blocks, tols):
+    want = ref.zfp_encode_blocks_fa_ref(blocks, tols)
+    got = ops.zfp_encode_blocks_fa(blocks, tols)
+    for name, w, g in zip(("payload", "emax", "nplanes"), want, got):
+        assert np.array_equal(np.asarray(g), np.asarray(w)), name
+    return want
+
+
+@pytest.mark.parametrize("n_blocks", [1, 127, 129, FA_TILE, FA_TILE + 1])
+def test_zfp_encode_fa_block_counts_bit_identical(rng, n_blocks):
+    """Part rows of 128 lanes, one whole tile and a tile's spill-over."""
+    blocks = _blocks_from(rng, n_blocks, "rough")
+    tols = jnp.asarray(10.0 ** rng.uniform(-5, 0, n_blocks), jnp.float32)
+    _assert_fa_encode_matches_ref(blocks, tols)
+
+
+def _fa_special_case(rng, case):
+    """(256 blocks, 256 tolerances) for one of the edge cases below."""
+    x = np.array(_blocks_from(rng, 256, "rough"))
+    tols = np.full((256,), 1e-3, np.float32)
+    if case == "zero_blocks":
+        x[::2] = 0.0
+        x[1::4] = 1e-40                          # below the 2^-120 flush
+    elif case == "all_fix_steps":
+        # a tolerance no plane count meets: every block takes all
+        # MAX_FIX_ITERS steps, two planes each, from a guess of emax + 2
+        x = rng.standard_normal((256, 16)).astype(np.float32) * 0.1
+        tols[:] = -1.0
+    elif case == "pow2_tolerance":
+        tols[:] = 2.0 ** -7
+        x[::3, 5] = 2.0 ** rng.integers(-8, 8, x[::3].shape[0])
+    elif case == "subnormal_and_large":
+        x[:64] = rng.choice([1e-39, -3e-42, 0.0, 5e-45], (64, 16))
+        x[64:96, ::2] = 2e-38
+        x[96:160] = rng.choice([-1.0, 1.0], (64, 16)) * 2e38
+        x[160:192] = rng.standard_normal((32, 16)) * 1e30
+        tols[96:192] = 1e25
+    elif case == "tolerances_change_in_tile":
+        tols = np.repeat(np.float32([1e-4, 0.3, 2.0 ** -3, 1e-2]), 64)
+    return jnp.asarray(x, jnp.float32), jnp.asarray(tols)
+
+
+@pytest.mark.parametrize("case", ["zero_blocks", "all_fix_steps",
+                                  "pow2_tolerance", "subnormal_and_large",
+                                  "tolerances_change_in_tile"])
+def test_zfp_encode_fa_edge_cases_bit_identical(rng, case):
+    from repro.compression.zfp import GUARD_BITS, MAX_FIX_ITERS
+    blocks, tols = _fa_special_case(rng, case)
+    _, emax, npl = _assert_fa_encode_matches_ref(blocks, tols)
+    if case == "all_fix_steps":
+        guess = np.clip(np.asarray(emax) + GUARD_BITS, 0, T.TOTAL_PLANES)
+        assert np.array_equal(np.asarray(npl),
+                              np.minimum(guess + 2 * MAX_FIX_ITERS,
+                                         T.TOTAL_PLANES))
+        assert (guess + 2 * MAX_FIX_ITERS <= T.TOTAL_PLANES).all()
+    if case == "zero_blocks":
+        assert not np.asarray(npl)[::2].any()
+        assert not np.asarray(npl)[1::4].any()
+
+
+@pytest.mark.parametrize("shape", [(2, 6, 32, 32), (2, 6, 48, 16),
+                                   (2, 3, 30, 18)],
+                         ids=["pchip", "rt", "ragged"])
+def test_encode_fixed_accuracy_batch_pallas_matches_jnp(rng, shape):
+    """Small stand-ins of the PCHIP (square) and RT (3:1) stacks, and one
+    whose H and W are not multiples of 4: ``use_pallas=True`` equals the
+    jnp encoder, and so does the kernel on the same coefficient-major stack."""
+    from repro.compression import encode_fixed_accuracy_batch
+    xs = jnp.asarray(rng.standard_normal(shape).astype(np.float32)
+                     * 10.0 ** rng.uniform(-2, 2, shape[:2] + (1, 1)))
+    tols = jnp.asarray(10.0 ** rng.uniform(-4, -1, shape[0]), jnp.float32)
+    want = encode_fixed_accuracy_batch(xs, tols)
+    got = encode_fixed_accuracy_batch(xs, tols, use_pallas=True)
+    assert (got.shape, got.padded_shape) == (want.shape, want.padded_shape)
+    n, nb = want.emax.shape
+    coefs = T.blockify_coef_major(T.pad_to_blocks(xs))
+    kernel = zfp_codec.zfp_encode_blocks_fa(coefs, jnp.repeat(tols, nb),
+                                            interpret=True)
+    for name, k in zip(("payload", "emax", "nplanes"), kernel):
+        w = np.asarray(getattr(want, name))
+        assert np.array_equal(np.asarray(getattr(got, name)), w), name
+        assert np.array_equal(np.asarray(k).reshape(w.shape), w), name
 
 
 # ---------------------------------------------------------------------------

@@ -8,6 +8,8 @@ module fixture below, so importing this file loads no TPU library and every
 test worker collects the same tests.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -27,6 +29,9 @@ SAMPLE = (6, 96, 32)
 BLOCKS_PER_SAMPLE = 6 * (96 // 4) * (32 // 4)
 NB = BATCH * BLOCKS_PER_SAMPLE                   # 73,728
 RESIDENT_SAMPLES = 5100                          # 100 simulations x 51 snapshots
+# one fixed-accuracy encode chunk of certification: 17 PCHIP snapshots x 6
+# fields x 512 x 512, 1,671,168 blocks
+CERTIFY_CHUNK = (17, 6, 512, 512)
 
 
 @pytest.fixture(scope="module")
@@ -69,7 +74,7 @@ def _kernel_cases(sharding):
         "encode": lambda: zfp_codec.zfp_encode_blocks.lower(
             blocks, bits_per_value=TOTAL_PLANES),
         "encode_fa": lambda: zfp_codec.zfp_encode_blocks_fa.lower(
-            blocks, _spec((NB,), f32, sharding)),
+            _spec((16, NB), f32, sharding), _spec((NB,), f32, sharding)),
     }
 
 
@@ -78,6 +83,20 @@ def _kernel_cases(sharding):
 def test_codec_kernel_compiles_for_v5e(one_chip, kernel):
     compiled = _kernel_cases(one_chip)[kernel]().compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_certify_chunk_encode_is_lane_dense(one_chip):
+    """A certification chunk's fixed-accuracy encode carries the kernel's
+    custom call under the name ``bench/metrics/encode_roofline.py`` reads,
+    and no operand padded out to 128 lanes: the block-major (nb, 16) layout
+    took 4 GiB of temporaries here."""
+    from repro.compression import encode_fixed_accuracy_batch
+    compiled = encode_fixed_accuracy_batch.lower(
+        _spec(CERTIFY_CHUNK, jnp.float32, one_chip),
+        _spec(CERTIFY_CHUNK[:1], jnp.float32, one_chip),
+        use_pallas=True).compile()
+    assert re.search(r"%zfp_encode_blocks_fa\.\d+ = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
 def test_fused_train_step_compiles_with_decode_kernel(one_chip):
