@@ -25,6 +25,8 @@ class ArchConfig:
     ssm_heads: int = 0
     ssm_head_dim: int = 64
     ssm_conv: int = 4
+    ssm_groups: int = 1             # B/C groups; head h reads h // (heads/groups)
+    ssm_conv_bias: bool = False
     # hybrid (hymba): parallel attn + SSM heads; SWA except global layers
     hybrid: bool = False
     attn_window: int = 0            # sliding-window size; 0 = full attention
@@ -39,6 +41,17 @@ class ArchConfig:
     tie_embeddings: bool = False
     rope_theta: float = 1e4
     norm_eps: float = 1e-6
+    # muP multipliers (Falcon-H1); 1.0 leaves the path as it was.  A hybrid
+    # layer adds ssm_out * SSM(ssm_in * h) + attn_out * Attn(attn_in * h)
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    attn_in_multiplier: float = 1.0
+    attn_out_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Tuple[float, ...] = (1.0,) * 5   # in_proj's z, x, B, C, dt
+    mlp_multipliers: Tuple[float, float] = (1.0, 1.0)  # gate, down
     # execution
     param_dtype: str = "bfloat16"
     remat: str = "full"             # full | dots | none

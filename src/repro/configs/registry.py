@@ -22,13 +22,34 @@ def _register(cfg: ArchConfig) -> ArchConfig:
 
 # --- hybrid ---------------------------------------------------------------
 # hymba-1.5b [arXiv:2411.13676]: 32L d=1600 25H (kv=5) ff=5504 v=32001,
-# parallel attn+mamba heads, SWA + 3 global-attn layers, ssm_state=16
+# parallel attn+mamba heads averaged, SWA + 3 global-attn layers, ssm_state=16
 HYMBA_1P5B = _register(ArchConfig(
     name="hymba-1.5b", family="hybrid", hybrid=True,
     num_layers=32, d_model=1600, num_heads=25, num_kv_heads=5,
     d_ff=5504, vocab_size=32001, head_dim=64,
     ssm_state=16, ssm_heads=50, ssm_head_dim=64,
-    attn_window=1024, global_attn_layers=(0, 15, 31)))
+    attn_window=1024, global_attn_layers=(0, 15, 31),
+    attn_out_multiplier=0.5, ssm_out_multiplier=0.5))
+
+# falcon-h1-34b [hf:tiiuae/Falcon-H1-34B-Instruct config.json]: 72L d=5120
+# 20H (kv=4) x 128, Mamba-2 32 heads x 128 (d_ssm 4096), state 256, 2 B/C
+# groups, conv 4 with bias, gated RMSNorm per group, ff=21504 v=261120
+# untied, rope 1e11, muP multipliers; attention and SSM in parallel on the
+# same normalised input in every layer
+FALCON_H1_34B = _register(ArchConfig(
+    name="falcon-h1-34b", family="hybrid", hybrid=True,
+    num_layers=72, d_model=5120, num_heads=20, num_kv_heads=4, head_dim=128,
+    d_ff=21504, vocab_size=261120,
+    ssm_state=256, ssm_heads=32, ssm_head_dim=128, ssm_conv=4,
+    ssm_groups=2, ssm_conv_bias=True,
+    rope_theta=1e11, norm_eps=1e-5,
+    embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+    key_multiplier=0.011048543456039804,
+    attn_in_multiplier=1.0, attn_out_multiplier=0.0375,
+    ssm_in_multiplier=0.25, ssm_out_multiplier=0.08838834764831845,
+    ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+    mlp_multipliers=(0.1767766952966369, 0.011160714285714284)))
 
 # --- audio enc-dec ---------------------------------------------------------
 # seamless-m4t-large-v2 [arXiv:2308.11596]: 24L d=1024 16H (kv=16) ff=8192

@@ -66,12 +66,14 @@ def _layer_param_shapes(cfg: ArchConfig, cross_attn: bool = False) -> Dict[str, 
     elif cfg.family != "ssm" or cfg.hybrid:
         shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
     if cfg.family == "ssm" or cfg.hybrid:
-        nh, p, n, k = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
-        di = nh * p
-        shapes.update(ssm_in=(d, 2 * di + 2 * n + nh),
-                      ssm_conv_w=(k, di + 2 * n),
+        nh, p, k = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_conv
+        di, gn = nh * p, cfg.ssm_groups * cfg.ssm_state
+        shapes.update(ssm_in=(d, 2 * di + 2 * gn + nh),
+                      ssm_conv_w=(k, di + 2 * gn),
                       ssm_A=(nh,), ssm_D=(nh,), ssm_dt_bias=(nh,),
                       ssm_norm=(di,), ssm_out=(di, d))
+        if cfg.ssm_conv_bias:
+            shapes["ssm_conv_b"] = (di + 2 * gn,)
         if cfg.family == "ssm":
             shapes["w_gate"] = (d, max(f, 1)) if f else None
             shapes.pop("w_gate")                # pure mamba2 has no MLP block
@@ -132,6 +134,11 @@ def rmsnorm(g, x, eps):
     xf = x.astype(jnp.float32)
     var = jnp.mean(jnp.square(xf), -1, keepdims=True)
     return ((xf * jax.lax.rsqrt(var + eps)).astype(x.dtype)) * g
+
+
+def _scale(x, m: float):
+    """``x * m`` in x's dtype; a multiplier of 1 adds no op."""
+    return x if m == 1.0 else x * m
 
 
 def rope(x, positions, theta):
@@ -238,34 +245,34 @@ def attention(q, k, v, qpos, kpos, *, causal=True, window=None, chunk=1024,
     """Memory-efficient attention: scan over q chunks; no S x S tensor.
 
     q: (B, Sq, H, Dh); k/v: (B, Sk, Hkv, Dh); positions (B, Sq)/(B, Sk).
-    GQA is realized by repeating KV heads to H (the Megatron convention when
-    kv_heads < TP) so the head axis shards cleanly over "model".
+    GQA: query head h reads KV head h // (H / Hkv); the query heads are
+    grouped by their KV head, so the KV heads are never repeated.
     ``seq_sharded``: decode path -- the KV cache is sequence-sharded over
     "model"; scores are constrained over their Sk dim instead of heads
     (flash-decoding style sharded softmax; GSPMD inserts the reductions).
     """
     b, sq, h, dh = q.shape
     _, sk, hkv, _ = k.shape
-    if hkv != h:
-        rep = h // hkv
-        k = jnp.repeat(k, rep, axis=2)
-        v = jnp.repeat(v, rep, axis=2)
+    rep = h // hkv
+    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
     scale = 1.0 / math.sqrt(dh)
     score_spec = (DP, None, None, "model") if seq_sharded \
         else (DP, "model", None, None)
 
     def block(q_blk, qpos_blk):
         # q_blk: (B, c, H, Dh) -> scores (B, H, c, Sk)
-        s = jnp.einsum("bqhd,bkhd->bhqk", q_blk.astype(jnp.float32),
-                       k.astype(jnp.float32)) * scale
+        c = q_blk.shape[1]
+        qg = q_blk.astype(jnp.float32).reshape(b, c, hkv, rep, dh)
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k).reshape(
+            b, h, c, sk) * scale
         m = kpos[:, None, None, :] <= qpos_blk[:, None, :, None] \
             if causal else jnp.ones_like(s, bool)
         w = window_dyn if window_dyn is not None else window
         if w is not None:
             m &= kpos[:, None, None, :] > qpos_blk[:, None, :, None] - w
         s = _constrain(jnp.where(m, s, -1e30), *score_spec)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, v.astype(jnp.float32))
+        p = jax.nn.softmax(s, axis=-1).reshape(b, hkv, rep, c, sk)
+        o = jnp.einsum("bgrqk,bkgd->bqgrd", p, v).reshape(b, c, h, dh)
         return _constrain(o, DP, None, "model", None)
 
     if sq <= chunk:
@@ -290,11 +297,13 @@ def attention(q, k, v, qpos, kpos, *, causal=True, window=None, chunk=1024,
     return out.astype(q.dtype)
 
 
-def swiglu(x, w_gate, w_up, w_down):
+def swiglu(x, w_gate, w_up, w_down, multipliers=(1.0, 1.0)):
+    """``m_down * W_down(silu(m_gate * W_gate x) * W_up x)``."""
     g = _constrain(jnp.einsum("bsd,df->bsf", x, w_gate), DP, None, "model")
+    g = _scale(g, multipliers[0])
     u = _constrain(jnp.einsum("bsd,df->bsf", x, w_up), DP, None, "model")
-    return jax.lax.optimization_barrier(
-        jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, w_down))
+    return _scale(jax.lax.optimization_barrier(
+        jnp.einsum("bsf,fd->bsd", jax.nn.silu(g) * u, w_down)), multipliers[1])
 
 
 # ===========================================================================
@@ -370,17 +379,19 @@ def _segsum(dA):
 
 def ssd_scan(xh, dt, A_log, Bm, Cm, chunk=256, init_state=None):
     """Chunked SSD.  xh: (B, S, H, P); dt: (B, S, H) (post-softplus);
-    A_log: (H,); Bm/Cm: (B, S, N).  Returns (y, final_state (B, H, P, N))."""
+    A_log: (H,); Bm/Cm: (B, S, G, N), head h reading group h // (H / G).
+    Returns (y, final_state (B, H, P, N))."""
     b, s, h, p = xh.shape
-    n = Bm.shape[-1]
+    g, n = Bm.shape[-2:]
+    hg = h // g
     c = min(chunk, s)
     nc = s // c
     a = -jnp.exp(A_log.astype(jnp.float32))                     # (H,) negative
-    dA = (dt * a).reshape(b, nc, c, h)                          # (B, NC, c, H)
-    xc = xh.reshape(b, nc, c, h, p)
-    bc = Bm.reshape(b, nc, c, n)
-    cc = Cm.reshape(b, nc, c, n)
-    dtc = dt.reshape(b, nc, c, h)
+    dA = (dt * a).reshape(b, nc, c, g, hg)                      # (B, NC, c, G, Hg)
+    xc = xh.reshape(b, nc, c, g, hg, p)
+    bc = Bm.reshape(b, nc, c, g, n)
+    cc = Cm.reshape(b, nc, c, g, n)
+    dtc = dt.reshape(b, nc, c, g, hg)
 
     if init_state is None:
         init_state = jnp.zeros((b, h, p, n), jnp.float32)
@@ -388,29 +399,29 @@ def ssd_scan(xh, dt, A_log, Bm, Cm, chunk=256, init_state=None):
     def chunk_step(state, xs):
         dA_k, x_k, b_k, c_k, dt_k = xs                          # leading b
         # within-chunk cumulative decays
-        cum = jnp.cumsum(dA_k, axis=1)                          # (B, c, H)
-        L = jnp.exp(_segsum(jnp.moveaxis(dA_k, -1, 1)))         # (B, H, c, c)
-        xw = x_k * dt_k[..., None]                              # weight by dt
+        cum = jnp.cumsum(dA_k, axis=1)                          # (B, c, G, Hg)
+        L = jnp.exp(_segsum(jnp.moveaxis(dA_k, 1, -1)))         # (B, G, Hg, c, c)
+        xw = (x_k * dt_k[..., None]).astype(jnp.float32)        # weight by dt
         # diagonal (intra-chunk): y[i] = sum_j<=i C_i.B_j L_ij x_j
-        cb = jnp.einsum("bin,bjn->bij", c_k, b_k)               # (B, c, c)
-        y_diag = jnp.einsum("bij,bhij,bjhp->bihp", cb, L,
-                            xw.astype(jnp.float32))
+        cb = jnp.einsum("bign,bjgn->bgij", c_k, b_k)            # (B, G, c, c)
+        y_diag = jnp.einsum("bgij,bgkij,bjgkp->bigkp", cb, L, xw)
         # inter-chunk: contribution of carried state
-        decay_in = jnp.exp(cum)                                 # (B, c, H)
-        y_off = jnp.einsum("bin,bhpn,bih->bihp", c_k.astype(jnp.float32),
+        decay_in = jnp.exp(cum)                                 # (B, c, G, Hg)
+        y_off = jnp.einsum("bign,bgkpn,bigk->bigkp", c_k.astype(jnp.float32),
                            state, decay_in)
         # new state: decay old + gather chunk
-        tot = cum[:, -1:, :]                                    # (B, 1, H)
-        decay_out = jnp.exp(tot - cum)                          # (B, c, H)
-        s_new = jnp.einsum("bin,bihp,bih->bhpn", b_k.astype(jnp.float32),
-                           xw.astype(jnp.float32), decay_out)
-        state = state * jnp.exp(tot[:, 0, :])[:, :, None, None] + s_new
+        tot = cum[:, -1:]                                       # (B, 1, G, Hg)
+        decay_out = jnp.exp(tot - cum)                          # (B, c, G, Hg)
+        s_new = jnp.einsum("bign,bigkp,bigk->bgkpn", b_k.astype(jnp.float32),
+                           xw, decay_out)
+        state = state * jnp.exp(tot[:, 0])[..., None, None] + s_new
         return state, (y_diag + y_off)
 
     xs = tuple(jnp.moveaxis(t, 1, 0) for t in (dA, xc, bc, cc, dtc))
-    final_state, yc = jax.lax.scan(chunk_step, init_state, xs)
+    final_state, yc = jax.lax.scan(chunk_step,
+                                   init_state.reshape(b, g, hg, p, n), xs)
     y = jnp.moveaxis(yc, 0, 1).reshape(b, s, h, p)
-    return y.astype(xh.dtype), final_state
+    return y.astype(xh.dtype), final_state.reshape(b, h, p, n)
 
 
 def _causal_conv(x, w, conv_state=None):
@@ -426,25 +437,52 @@ def _causal_conv(x, w, conv_state=None):
     return y, xp[:, -(k - 1):, :]
 
 
+def _mup_vector(cfg: ArchConfig, dtype):
+    """``in_proj``'s output multipliers over its z, x, B, C, dt segments."""
+    import numpy as np
+    di, gn = cfg.ssm_heads * cfg.ssm_head_dim, cfg.ssm_groups * cfg.ssm_state
+    widths = (di, di, gn, gn, cfg.ssm_heads)
+    return jnp.asarray(np.repeat(np.asarray(cfg.ssm_multipliers), widths),
+                       dtype)
+
+
+def _group_rmsnorm(w, x, groups: int, eps):
+    """RMSNorm over each of ``groups`` equal slices of the last axis."""
+    if groups == 1:
+        return rmsnorm(w, x, eps)
+    shape = x.shape
+    xg = x.reshape(*shape[:-1], groups, shape[-1] // groups)
+    return rmsnorm(w.reshape(groups, -1), xg, eps).reshape(shape)
+
+
 def ssm_block(lp, x, cfg: ArchConfig, conv_state=None, ssm_state=None,
               chunk=256, pad_mask=None):
     """Mamba2 block.  x: (B, S, D).  Returns (y, (conv_state, ssm_state)).
+
+    B and C come in ``cfg.ssm_groups`` groups (head h reads group
+    h // (heads / groups)); the gated RMSNorm normalises each group's
+    channels on their own.
 
     ``pad_mask`` (B, S) bool, True = real token: padding positions contribute
     nothing to the recurrent state (conv input zeroed, dt zeroed so the SSM
     state neither decays nor updates across pads) -- required for serving
     right-padded mixed-length prompt batches.
     """
-    nh, p, n = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-    di = nh * p
+    nh, p, n, g = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups
+    di, gn = nh * p, g * n
+    x = _scale(x, cfg.ssm_in_multiplier)
     zxbcdt = jnp.einsum("bsd,de->bse", x, lp["ssm_in"])
+    if any(m != 1.0 for m in cfg.ssm_multipliers):
+        zxbcdt = zxbcdt * _mup_vector(cfg, zxbcdt.dtype)
     z, xin, bm, cm, dt = jnp.split(
-        zxbcdt, [di, 2 * di, 2 * di + n, 2 * di + 2 * n], axis=-1)
+        zxbcdt, [di, 2 * di, 2 * di + gn, 2 * di + 2 * gn], axis=-1)
     xbc = jnp.concatenate([xin, bm, cm], -1)
     if pad_mask is not None:
         xbc = jnp.where(pad_mask[..., None], xbc, 0)
     xbc_in = xbc
     xbc, new_conv = _causal_conv(xbc, lp["ssm_conv_w"], conv_state)
+    if cfg.ssm_conv_bias:
+        xbc = xbc + lp["ssm_conv_b"]
     if pad_mask is not None:
         # the cached conv window must end at each slot's LAST REAL token,
         # not at the right-pad zeros: gather the per-slot (K-1)-wide window
@@ -458,7 +496,9 @@ def ssm_block(lp, x, cfg: ArchConfig, conv_state=None, ssm_state=None,
         cols = lens[:, None] + jnp.arange(kk - 1, dtype=jnp.int32)[None]
         new_conv = jnp.take_along_axis(xp, cols[:, :, None], axis=1)
     xbc = jax.nn.silu(xbc)
-    xin, bm, cm = jnp.split(xbc, [di, di + n], axis=-1)
+    xin, bm, cm = jnp.split(xbc, [di, di + gn], axis=-1)
+    bm = bm.reshape(*bm.shape[:2], g, n)
+    cm = cm.reshape(*cm.shape[:2], g, n)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["ssm_dt_bias"])
     if pad_mask is not None:
         # dt=0 freezes the state through pads: dA = exp(0 * a) = 1 and the
@@ -471,9 +511,11 @@ def ssm_block(lp, x, cfg: ArchConfig, conv_state=None, ssm_state=None,
         a = -jnp.exp(lp["ssm_A"].astype(jnp.float32))
         dA = jnp.exp(dt[:, 0] * a)                                 # (B, H)
         xw = (xh[:, 0] * dt[:, 0, :, None]).astype(jnp.float32)    # (B, H, P)
-        upd = jnp.einsum("bhp,bn->bhpn", xw, bm[:, 0].astype(jnp.float32))
+        bh = jnp.repeat(bm[:, 0], nh // g, axis=1).astype(jnp.float32)
+        ch = jnp.repeat(cm[:, 0], nh // g, axis=1).astype(jnp.float32)
+        upd = jnp.einsum("bhp,bhn->bhpn", xw, bh)                  # (B, H, N)
         state = ssm_state * dA[:, :, None, None] + upd
-        y = jnp.einsum("bhpn,bn->bhp", state, cm[:, 0].astype(jnp.float32))
+        y = jnp.einsum("bhpn,bhn->bhp", state, ch)
         y = y[:, None].reshape(x.shape[0], 1, nh, p)
         final_state = state
     else:
@@ -481,7 +523,7 @@ def ssm_block(lp, x, cfg: ArchConfig, conv_state=None, ssm_state=None,
                                   init_state=ssm_state)
     y = y + xh.astype(jnp.float32) * lp["ssm_D"][None, None, :, None]
     y = y.reshape(*x.shape[:2], di).astype(x.dtype)
-    y = rmsnorm(lp["ssm_norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    y = _group_rmsnorm(lp["ssm_norm"], y * jax.nn.silu(z), g, cfg.norm_eps)
     out = jax.lax.optimization_barrier(
         jnp.einsum("bse,ed->bsd", y, lp["ssm_out"]))
     return out, (new_conv, final_state)
@@ -505,6 +547,7 @@ def attn_block(lp, x, cfg: ArchConfig, positions, *, causal=True,
     """Self-attention sublayer.  Returns (y, new_kv) where new_kv is the
     (k, v) pair either freshly computed (prefill/train) or cache-updated."""
     q, k, v = _project_qkv(lp, x, cfg)
+    k = _scale(k, cfg.key_multiplier)
     q = _constrain(rope(q, positions, cfg.rope_theta), DP, None, "model", None)
     k = _constrain(rope(k, positions, cfg.rope_theta), DP, None, "model", None)
     v = _constrain(v, DP, None, "model", None)
@@ -560,35 +603,31 @@ def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
         big = jnp.int32(2**30)
         window_dyn = jnp.where(is_global, big, jnp.int32(cfg.attn_window))
 
-    if cfg.family == "ssm":
-        y, (conv_s, ssm_s) = ssm_block(
-            lp, h, cfg,
-            conv_state=None if cache is None else cache["conv"],
-            ssm_state=None if cache is None else cache["ssm"],
-            pad_mask=pad_mask)
+    y_ssm = y_attn = None
+    if cfg.family == "ssm" or cfg.hybrid:
+        with jax.named_scope("ssm_mixer"):
+            y_ssm, (conv_s, ssm_s) = ssm_block(
+                lp, h, cfg,
+                conv_state=None if cache is None else cache["conv"],
+                ssm_state=None if cache is None else cache["ssm"],
+                pad_mask=pad_mask)
         if cache is not None:
-            new_cache.update(conv=conv_s, ssm=ssm_s.astype(cache["ssm"].dtype))
-        x = x + y
-    elif cfg.hybrid:
-        y_attn, kv = attn_block(lp, h, cfg, positions, window_dyn=window_dyn,
-                                kv_cache=None if cache is None else
-                                (cache["k"], cache["v"]), cache_pos=cache_pos)
-        y_ssm, (conv_s, ssm_s) = ssm_block(
-            lp, h, cfg,
-            conv_state=None if cache is None else cache["conv"],
-            ssm_state=None if cache is None else cache["ssm"],
-            pad_mask=pad_mask)
-        if cache is not None:
-            new_cache.update(k=kv[0], v=kv[1], conv=conv_s,
+            new_cache.update(conv=conv_s.astype(cache["conv"].dtype),
                              ssm=ssm_s.astype(cache["ssm"].dtype))
-        x = x + 0.5 * (y_attn + y_ssm)
-    else:
-        y, kv = attn_block(lp, h, cfg, positions,
-                           kv_cache=None if cache is None else
-                           (cache["k"], cache["v"]), cache_pos=cache_pos)
+        y_ssm = _scale(y_ssm, cfg.ssm_out_multiplier)
+    if cfg.family != "ssm":
+        with jax.named_scope("attn_mixer"):
+            y_attn, kv = attn_block(
+                lp, _scale(h, cfg.attn_in_multiplier), cfg, positions,
+                window_dyn=window_dyn,
+                kv_cache=None if cache is None else (cache["k"], cache["v"]),
+                cache_pos=cache_pos)
         if cache is not None:
             new_cache.update(k=kv[0], v=kv[1])
-        x = x + y
+        y_attn = _scale(y_attn, cfg.attn_out_multiplier)
+    # hybrid: x + m_ssm_out * SSM(m_ssm_in * h) + m_attn_out * Attn(m_attn_in * h)
+    x = x + (y_attn if y_ssm is None else y_ssm if y_attn is None
+             else y_ssm + y_attn)
 
     if enc_out is not None or (cache is not None and "xk" in cache):
         # cross-attention; decode uses the prefill-computed cross-KV cache
@@ -613,10 +652,12 @@ def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
 
     if cfg.family != "ssm" or cfg.hybrid:
         h = rmsnorm(lp["ln2"], x, cfg.norm_eps)
-        if cfg.num_experts:
-            y, aux = moe_block(lp, h, cfg)
-        else:
-            y = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
+        with jax.named_scope("mlp"):
+            if cfg.num_experts:
+                y, aux = moe_block(lp, h, cfg)
+            else:
+                y = swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"],
+                           cfg.mlp_multipliers)
         x = x + y
     if cfg.seq_parallel:
         # Megatron-SP: the stored (remat-saved) residual stream is S-sharded
@@ -658,7 +699,8 @@ def _global_flags(cfg: ArchConfig):
 
 def _embed_inputs(params, cfg: ArchConfig, batch):
     """tokens (+ optional frontend embeddings) -> (B, S, D), positions."""
-    x = jnp.take(params["embed"], batch["tokens"], axis=0)
+    x = _scale(jnp.take(params["embed"], batch["tokens"], axis=0),
+               cfg.embedding_multiplier)
     if cfg.frontend != "none" and "frontend_embeds" in batch:
         fe = jnp.einsum("bsf,fd->bsd", batch["frontend_embeds"].astype(x.dtype),
                         params["frontend_proj"])
@@ -712,6 +754,12 @@ def _head_weight(params, cfg):
     return params["embed"].T if cfg.tie_embeddings else params["lm_head"]
 
 
+def _logits(params, cfg: ArchConfig, x):
+    """(B, S, D) final hidden states -> float32 (B, S, V) logits."""
+    logits = jnp.einsum("bsd,dv->bsv", x, _head_weight(params, cfg))
+    return _scale(logits.astype(jnp.float32), cfg.lm_head_multiplier)
+
+
 def lm_loss(params, cfg: ArchConfig, batch, vocab_chunk_tokens: int = 512):
     """Next-token CE, chunked over the sequence (no (tokens, vocab) tensor)."""
     hidden, aux = lm_forward(params, cfg, batch)
@@ -729,9 +777,9 @@ def lm_loss(params, cfg: ArchConfig, batch, vocab_chunk_tokens: int = 512):
     def chunk_ce(hx, lx):
         hx = _constrain(hx, DP, None, None)
         lx = _constrain(lx, DP, None)
-        logits = _constrain(
+        logits = _constrain(_scale(
             jnp.einsum("bcd,dv->bcv", hx, w).astype(jnp.float32),
-            DP, None, "model")
+            cfg.lm_head_multiplier), DP, None, "model")
         lse = jax.nn.logsumexp(logits, -1)
         gold = jnp.take_along_axis(logits, lx[..., None], -1)[..., 0]
         return jnp.sum(lse - gold)
@@ -760,7 +808,8 @@ def init_cache(cfg: ArchConfig, batch: int, max_seq: int, dtype=jnp.bfloat16,
     if cfg.family == "ssm" or cfg.hybrid:
         nh, p, n, k = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_conv
         di = nh * p
-        cache["conv"] = jnp.zeros((l, batch, k - 1, di + 2 * n), dtype)
+        cache["conv"] = jnp.zeros(
+            (l, batch, k - 1, di + 2 * cfg.ssm_groups * n), dtype)
         cache["ssm"] = jnp.zeros((l, batch, nh, p, n), jnp.float32)
     if cfg.encoder_layers and enc_seq:
         cache["xk"] = jnp.zeros((l, batch, enc_seq, hkv, hd), dtype)
@@ -823,8 +872,7 @@ def lm_prefill(params, cfg: ArchConfig, batch, max_seq: int,
                                (b, 1, x.shape[-1]))
         x = jnp.take_along_axis(x, idx, axis=1)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, _head_weight(params, cfg))
-    return logits[:, 0].astype(jnp.float32), new_cache
+    return _logits(params, cfg, x)[:, 0], new_cache
 
 
 def serve_step(params, cfg: ArchConfig, cache, tokens, pos, enc_out=None):
@@ -832,7 +880,8 @@ def serve_step(params, cfg: ArchConfig, cache, tokens, pos, enc_out=None):
     length, uniform across the batch) or (B,) int32 vector of PER-SLOT
     lengths -- the continuous-batching contract, where recycled slots sit at
     independent generation depths.  Returns (logits (B, V), new_cache)."""
-    x = jnp.take(params["embed"], tokens[:, None], axis=0)
+    x = _scale(jnp.take(params["embed"], tokens[:, None], axis=0),
+               cfg.embedding_multiplier)
     b = x.shape[0]
     if jnp.ndim(pos) == 0:
         positions = jnp.full((b, 1), pos, jnp.int32)
@@ -849,8 +898,7 @@ def serve_step(params, cfg: ArchConfig, cache, tokens, pos, enc_out=None):
 
     x, new_cache = jax.lax.scan(body, x, (params["layers"], cache, flags))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = jnp.einsum("bsd,dv->bsv", x, _head_weight(params, cfg))
-    return logits[:, 0].astype(jnp.float32), new_cache
+    return _logits(params, cfg, x)[:, 0], new_cache
 
 
 def param_count(cfg: ArchConfig) -> int:

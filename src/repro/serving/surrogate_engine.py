@@ -35,7 +35,6 @@ on.  The device idles in every phase but ``device_wait``.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from functools import partial
@@ -49,6 +48,7 @@ from repro.models.surrogate import SurrogateConfig, apply_surrogate
 from repro.obs import jaxprof
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
+from repro.serving.phases import PhaseClock
 from repro.serving.scheduler import SlotScheduler
 
 
@@ -67,26 +67,6 @@ class SurrogateQuery:
 
 
 PHASES = ("no_work", "dispatch", "device_wait", "fetch", "collect")
-
-
-class _PhaseClock:
-    """Cumulative seconds per phase of ``run``'s loop on one chained clock:
-    a phase is charged from the end of the phase before it to its own end,
-    so the phases partition the loop's wall time."""
-
-    def __init__(self, t0: float):
-        reg = obs_metrics.get_registry()
-        self._counters = {p: reg.counter(f"surrogate_serve.{p}_seconds")
-                          for p in PHASES}
-        self._mark = t0
-
-    @contextlib.contextmanager
-    def __call__(self, phase: str):
-        with obs_trace.span("surrogate_serve." + phase, cat="serve"):
-            yield
-        now = time.perf_counter()
-        self._counters[phase].add(now - self._mark)
-        self._mark = now
 
 
 @partial(jax.jit, static_argnames=("cfg", "sigmas"))
@@ -178,7 +158,7 @@ class SurrogateServeEngine:
         t_start = time.perf_counter()
         clock = lambda: time.perf_counter() - t_start
         self._t_run_start = t_start
-        phase = _PhaseClock(t_start)
+        phase = PhaseClock("surrogate_serve", PHASES, t_start)
         reg = obs_metrics.get_registry()
         occ_hist = reg.histogram("surrogate_serve.slot_occupancy")
         tracer = obs_trace.get_tracer()

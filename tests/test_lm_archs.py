@@ -59,7 +59,8 @@ def test_arch_forward_output_shape(arch):
 
 @pytest.mark.parametrize("arch", _arch_params(["internlm2-1.8b", "mamba2-130m",
                                                "hymba-1.5b",
-                                               "qwen3-moe-30b-a3b"]))
+                                               "qwen3-moe-30b-a3b",
+                                               "falcon-h1-34b"]))
 def test_decode_matches_forward(arch):
     """KV/SSM/hybrid caches: step-by-step decode == full causal forward."""
     cfg = dataclasses.replace(reduced_config(arch), attn_chunk=16,
@@ -69,7 +70,8 @@ def test_decode_matches_forward(arch):
     toks = jax.random.randint(jax.random.PRNGKey(7), (b, s), 0, cfg.vocab_size)
     hidden, _ = jax.jit(lambda p: lm.lm_forward(p, cfg, {"tokens": toks}))(params)
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    full_logits = np.asarray(jnp.einsum("bsd,dv->bsv", hidden, w))
+    full_logits = np.asarray(jnp.einsum("bsd,dv->bsv", hidden, w)
+                             * cfg.lm_head_multiplier)
     cache = lm.init_cache(cfg, b, s, dtype=jnp.float32)
     step = jax.jit(lambda p, c, t, pos: lm.serve_step(p, cfg, c, t, pos))
     errs = []
@@ -108,7 +110,8 @@ def test_moe_router_load_balance_aux_positive():
 
 
 def test_param_counts_match_init():
-    for arch in ("internlm2-1.8b", "qwen3-moe-30b-a3b", "mamba2-130m"):
+    for arch in ("internlm2-1.8b", "qwen3-moe-30b-a3b", "mamba2-130m",
+                 "falcon-h1-34b"):
         cfg = reduced_config(arch)
         params = lm.init_lm(KEY, cfg)
         n_init = sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
@@ -128,7 +131,8 @@ def test_full_config_param_counts_sane():
                 "command-r-35b": (28e9, 40e9),  # GQA variant: 30.3B
                 "arctic-480b": (400e9, 520e9),
                 "qwen3-moe-30b-a3b": (25e9, 34e9),
-                "mamba2-130m": (1e8, 1.8e8)}
+                "mamba2-130m": (1e8, 1.8e8),
+                "falcon-h1-34b": (33.5e9, 33.7e9)}
     for arch, (lo, hi) in expected.items():
         n = lm.param_count(get_config(arch))
         assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B outside [{lo/1e9}, {hi/1e9}]"

@@ -10,6 +10,8 @@ Regression coverage for the PR-6 bug set:
   * ``tokens_per_second`` uses decode seconds only (prefill split out);
   * surrogate band width is consistent with ``core.variability``.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -379,3 +381,146 @@ def test_open_loop_latency_counts_queueing(fleet):
     done = eng.run(qs)
     assert len(done) == 3
     assert all(d.latency >= 0 for d in done)
+
+
+# ---------------------------------------------------------------------------
+# prefill length buckets and the engine's phases
+# ---------------------------------------------------------------------------
+
+BUCKET_ARCHS = ["internlm2-1.8b", "mamba2-130m", "falcon-h1-34b"]
+
+
+@pytest.fixture(scope="module")
+def bucket_setup():
+    out = {}
+    for arch in BUCKET_ARCHS:
+        # a name of its own: these tests count this config's compiles
+        cfg = dataclasses.replace(reduced_config(arch), name=arch + "-buckets")
+        out[arch] = (cfg, lm.init_lm(jax.random.PRNGKey(1), cfg))
+    return out
+
+
+def _heavy_tailed(cfg, n, seed, max_prompt=30):
+    rng = np.random.default_rng(seed)
+    lens = np.clip(np.round(4 * np.exp(rng.standard_normal(n))), 1,
+                   max_prompt).astype(int)
+    return [Request(rng.integers(0, cfg.vocab_size, p).astype(np.int32),
+                    int(rng.integers(1, 6)), arrival=0.002 * i)
+            for i, p in enumerate(lens)]
+
+
+@pytest.mark.parametrize("arch", BUCKET_ARCHS)
+def test_bucketed_batch_matches_solo(bucket_setup, arch):
+    """Prompts spread over four buckets, four slots: each request's tokens
+    equal those it gets alone in a one-slot engine."""
+    cfg, params = bucket_setup[arch]
+    buckets = (4, 8, 16, 32)
+    reqs = _heavy_tailed(cfg, 8, seed=4)
+    assert len({ServeEngine(params, cfg, 1, 40, buckets).bucket(
+        len(r.prompt)) for r in reqs}) >= 3
+    batched = ServeEngine(params, cfg, batch_slots=4, max_seq=40,
+                          prefill_buckets=buckets).run(
+        [Request(r.prompt.copy(), r.max_new_tokens, r.arrival)
+         for r in reqs])
+    by_prompt = {tuple(d.prompt.tolist()): d.output for d in batched}
+    for r in reqs:
+        solo = ServeEngine(params, cfg, batch_slots=1, max_seq=40,
+                           prefill_buckets=buckets).run(
+            [Request(r.prompt.copy(), r.max_new_tokens)])[0].output
+        assert np.array_equal(by_prompt[tuple(r.prompt.tolist())], solo)
+
+
+def test_heavy_tailed_mix_compiles_only_the_buckets(bucket_setup):
+    """Warm-up compiles one prefill program per bucket and one decode step;
+    a heavy-tailed prompt mix then compiles nothing, and the recompile
+    watcher, which watches prefill too, flags nothing."""
+    from repro.obs import jaxprof
+    cfg, params = bucket_setup["falcon-h1-34b"]
+    cfg = dataclasses.replace(cfg, name="falcon-h1-heavy-tail")
+    buckets = (4, 8, 16, 32)
+    eng = ServeEngine(params, cfg, batch_slots=4, max_seq=40,
+                      prefill_buckets=buckets)
+    sizes = lambda: (engine_mod._prefill._cache_size(),
+                     engine_mod._decode_step._cache_size())
+    before = sizes()
+    eng.warmup()
+    warm = sizes()
+    assert warm[0] - before[0] == len(buckets)
+    assert warm[1] - before[1] == 1
+    reqs = _heavy_tailed(cfg, 24, seed=9)
+    assert max(len(r.prompt) for r in reqs) > 16
+    done = eng.run(reqs)
+    assert len(done) == len(reqs)
+    assert sizes() == warm
+    assert jaxprof.get_watcher().check() == []
+    assert {"serve.prefill", "serve.decode_step"} <= set(
+        jaxprof.get_watcher().sizes())
+
+
+def test_prompt_longer_than_every_bucket_is_refused(bucket_setup):
+    cfg, params = bucket_setup["internlm2-1.8b"]
+    eng = ServeEngine(params, cfg, batch_slots=1, max_seq=40,
+                      prefill_buckets=(8, 16))
+    with pytest.raises(ValueError, match="bucket"):
+        eng.run([Request(np.zeros(17, np.int32), 2)])
+    with pytest.raises(ValueError, match="max_seq"):
+        ServeEngine(params, cfg, max_seq=16, prefill_buckets=(8, 32))
+
+
+def test_lm_phases_partition_the_run_wall_time(bucket_setup):
+    """The ``lm_serve.<phase>_seconds`` counters of one run sum to its wall
+    time, and the token counters count prompts, bucket padding and the
+    decode steps' tokens."""
+    import time
+    from repro.obs.metrics import get_registry
+    from repro.serving.engine import PHASES
+    cfg, params = bucket_setup["falcon-h1-34b"]
+    eng = ServeEngine(params, cfg, batch_slots=3, max_seq=40,
+                      prefill_buckets=(8, 16, 32))
+    eng.warmup()
+    reqs = _heavy_tailed(cfg, 10, seed=2)
+    for i, r in enumerate(reqs):
+        r.arrival = 0.03 * (i + 1)           # the first pass finds no work
+    reg = get_registry()
+    names = [f"lm_serve.{p}_seconds" for p in PHASES] + [
+        "lm_serve.prefill_tokens", "lm_serve.prefill_pad_tokens",
+        "lm_serve.decode_tokens"]
+    snap = lambda: {k: reg.snapshot().get(k, 0.0) for k in names}
+    s0 = snap()
+    t0 = time.perf_counter()
+    done = eng.run(reqs)
+    wall = time.perf_counter() - t0
+    d = {k: v - s0[k] for k, v in snap().items()}
+    phases = sum(d[f"lm_serve.{p}_seconds"] for p in PHASES)
+    # the run's own set-up and final check lie outside the phases
+    assert phases == pytest.approx(wall, abs=1e-2)
+    assert phases < wall
+    assert all(d[f"lm_serve.{p}_seconds"] > 0 for p in PHASES)
+    plens = [len(r.prompt) for r in done]
+    assert d["lm_serve.prefill_tokens"] == sum(plens)
+    assert d["lm_serve.prefill_pad_tokens"] == sum(
+        eng.bucket(p) - p for p in plens)
+    assert d["lm_serve.decode_tokens"] == sum(len(r.output) - 1
+                                              for r in done)
+
+
+def test_kept_logits_stop_at_their_budget(bucket_setup):
+    """A request keeps ``keep_logits`` rows -- its prefill's last row, then
+    one a decode step -- and no more, read back from its own slot: the rows
+    equal the first rows of a request that keeps every one, and a request
+    that keeps none holds none."""
+    cfg, params = bucket_setup["falcon-h1-34b"]
+    eng = ServeEngine(params, cfg, batch_slots=3, max_seq=40,
+                      prefill_buckets=(8, 16, 32))
+    reqs = _heavy_tailed(cfg, 6, seed=5)
+    for r in reqs:
+        r.max_new_tokens = 5
+    mk = lambda keep: [Request(r.prompt.copy(), 5, r.arrival,
+                               keep_logits=keep) for r in reqs]
+    full, part, none = (eng.run(mk(k)) for k in (5, 2, 0))
+    by_prompt = {tuple(d.prompt.tolist()): d for d in full}
+    for d in part:
+        rows = by_prompt[tuple(d.prompt.tolist())].logits
+        assert len(rows) == 5 and len(d.logits) == 2
+        np.testing.assert_array_equal(np.stack(d.logits), np.stack(rows[:2]))
+    assert all(d.logits is None for d in none)

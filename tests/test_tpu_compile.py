@@ -123,3 +123,47 @@ def test_fused_train_step_compiles_with_decode_kernel(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 16e9
+
+
+def test_falcon_h1_stage_serves_on_one_chip(one_chip):
+    """The ``serve-falcon-h1`` cell's programs at their timed shapes (one
+    8-layer stage at published widths, 16 slots of 5,120 positions, the
+    largest prefill bucket) compile for one v5e; both update the donated
+    cache in place, and the decode step, with the weights and cache it is
+    handed, fits the chip's 16 GiB."""
+    import json
+    import os
+    from bench.loops import lm_serve
+    from repro.models import lm
+    from repro.serving import engine
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "falcon-h1-34b-8l.json")) as f:
+        conf = json.load(f)
+    with open(os.path.join(root, "bench", "traffic",
+                           "lm-longctx-poisson.json")) as f:
+        longest = max(json.load(f)["prefill_buckets"])
+    cfg = lm_serve.arch_config(conf)
+    slots, max_seq = conf["serving"]["slots"], conf["serving"]["max_seq"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: lm.init_lm(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(
+        lambda: lm.init_cache(cfg, slots, max_seq, jnp.float32)))
+    ids = _spec((slots,), jnp.int32, one_chip)
+    decode = engine._decode_step.lower(params, cfg, cache, ids,
+                                       ids).compile().memory_analysis()
+    cache_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(cache))
+    assert decode.alias_size_in_bytes >= cache_bytes
+    assert decode.argument_size_in_bytes + decode.temp_size_in_bytes < \
+        15 * 2 ** 30
+    one = _spec((1,), jnp.int32, one_chip)
+    prefill = engine._prefill.lower(
+        params, cfg, cache, _spec((1, longest), jnp.int32, one_chip), one,
+        one, max_seq).compile().memory_analysis()
+    assert prefill.alias_size_in_bytes >= cache_bytes
+    assert prefill.temp_size_in_bytes < 2 ** 30
