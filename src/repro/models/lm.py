@@ -542,33 +542,60 @@ def _project_qkv(lp, x, cfg, prefix=""):
     return q, k, v
 
 
+def _layer_of(c, layer):
+    """Layer ``layer``'s slice of a stacked cache leaf (``c`` itself when
+    ``layer`` is None: the leaf is already one layer's)."""
+    return c if layer is None else c[layer]
+
+
+def _store(c, new, layer):
+    """``new`` as layer ``layer``'s slice of cache leaf ``c``: written into
+    the stacked leaf at index ``layer``, or ``new`` itself when ``layer`` is
+    None.  Cast to the cache's dtype either way."""
+    new = new.astype(c.dtype)
+    return new if layer is None else c.at[layer].set(new)
+
+
+def _write_rows(c, new, cache_pos, layer=None):
+    """Write ``new`` (B, s, Hkv, Dh) into KV cache ``c`` (B, S, Hkv, Dh), or
+    into layer ``layer`` of a stacked (L, B, S, Hkv, Dh) one, at rows
+    ``cache_pos`` .. ``cache_pos + s``.  ``cache_pos`` is a scalar (every
+    slot writes at one position) or a (B,) vector of per-slot positions
+    (continuous batching: slots decode at independent depths).  Only those
+    rows change, so a donated cache is updated in place."""
+    new = new.astype(c.dtype)
+    lead = () if layer is None else (layer,)
+    if jnp.ndim(cache_pos) == 0:
+        start = lead + (0, cache_pos, 0, 0)
+        return jax.lax.dynamic_update_slice(c, new.reshape(
+            (1,) * len(lead) + new.shape), start)
+    rows = jnp.arange(new.shape[0], dtype=jnp.int32)[:, None]
+    cols = cache_pos[:, None] + jnp.arange(new.shape[1], dtype=jnp.int32)[None]
+    return c.at[lead + (rows, cols)].set(new)
+
+
 def attn_block(lp, x, cfg: ArchConfig, positions, *, causal=True,
-               window_dyn=None, kv_cache=None, cache_pos=None):
+               window_dyn=None, kv_cache=None, cache_pos=None, layer=None):
     """Self-attention sublayer.  Returns (y, new_kv) where new_kv is the
-    (k, v) pair either freshly computed (prefill/train) or cache-updated."""
+    (k, v) pair either freshly computed (prefill/train) or cache-updated.
+
+    With ``layer``, ``kv_cache`` holds every layer's (L, B, S, Hkv, Dh)
+    stacks: this layer's new rows are written into them and the returned
+    stacks differ from the input only there."""
     q, k, v = _project_qkv(lp, x, cfg)
     k = _scale(k, cfg.key_multiplier)
     q = _constrain(rope(q, positions, cfg.rope_theta), DP, None, "model", None)
     k = _constrain(rope(k, positions, cfg.rope_theta), DP, None, "model", None)
     v = _constrain(v, DP, None, "model", None)
     if kv_cache is not None:
-        ck, cv = kv_cache
-        if jnp.ndim(cache_pos) == 0:
-            ck = jax.lax.dynamic_update_slice_in_dim(ck, k.astype(ck.dtype), cache_pos, 1)
-            cv = jax.lax.dynamic_update_slice_in_dim(cv, v.astype(cv.dtype), cache_pos, 1)
-        else:
-            # per-slot write positions (continuous batching: slots decode at
-            # independent depths); rows land at cache_pos[b] .. cache_pos[b]+s
-            rows = jnp.arange(ck.shape[0], dtype=jnp.int32)[:, None]
-            cols = cache_pos[:, None] + jnp.arange(k.shape[1],
-                                                   dtype=jnp.int32)[None]
-            ck = ck.at[rows, cols].set(k.astype(ck.dtype))
-            cv = cv.at[rows, cols].set(v.astype(cv.dtype))
-        sk = ck.shape[1]
+        ck, cv = (_write_rows(c, t, cache_pos, layer)
+                  for c, t in zip(kv_cache, (k, v)))
+        lk, lv = _layer_of(ck, layer), _layer_of(cv, layer)
+        sk = lk.shape[1]
         kpos = jnp.broadcast_to(jnp.arange(sk, dtype=jnp.int32)[None],
                                 (x.shape[0], sk))
         valid = kpos <= positions[:, -1:]
-        y = attention(q, ck.astype(q.dtype), cv.astype(q.dtype), positions,
+        y = attention(q, lk.astype(q.dtype), lv.astype(q.dtype), positions,
                       jnp.where(valid, kpos, jnp.int32(2**30)),
                       causal=causal, window=cfg.attn_window or None,
                       window_dyn=window_dyn, chunk=cfg.attn_chunk,
@@ -585,13 +612,17 @@ def attn_block(lp, x, cfg: ArchConfig, positions, *, causal=True,
 
 
 def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
-                  enc_out=None, cache=None, cache_pos=None, pad_mask=None):
+                  enc_out=None, cache=None, cache_pos=None, pad_mask=None,
+                  layer=None):
     """One decoder layer.  Returns (x, new_cache, aux_loss).
 
     ``cache_pos`` may be a scalar (uniform write position, the historical
     prefill/lockstep-decode contract) or a (B,) vector of per-slot positions
     (continuous-batching decode: every slot sits at its own depth).
     ``pad_mask`` (B, S) marks real tokens in a right-padded prefill batch.
+    ``layer`` (the decode step): ``cache`` is the whole stacked cache, every
+    leaf with its leading L axis, and ``new_cache`` is that cache with this
+    layer's new KV rows and SSM/conv state written in at index ``layer``.
     """
     lp = _gather_weights(lp)
     aux = jnp.zeros((), jnp.float32)
@@ -608,12 +639,14 @@ def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
         with jax.named_scope("ssm_mixer"):
             y_ssm, (conv_s, ssm_s) = ssm_block(
                 lp, h, cfg,
-                conv_state=None if cache is None else cache["conv"],
-                ssm_state=None if cache is None else cache["ssm"],
+                conv_state=None if cache is None
+                else _layer_of(cache["conv"], layer),
+                ssm_state=None if cache is None
+                else _layer_of(cache["ssm"], layer),
                 pad_mask=pad_mask)
         if cache is not None:
-            new_cache.update(conv=conv_s.astype(cache["conv"].dtype),
-                             ssm=ssm_s.astype(cache["ssm"].dtype))
+            new_cache.update(conv=_store(cache["conv"], conv_s, layer),
+                             ssm=_store(cache["ssm"], ssm_s, layer))
         y_ssm = _scale(y_ssm, cfg.ssm_out_multiplier)
     if cfg.family != "ssm":
         with jax.named_scope("attn_mixer"):
@@ -621,7 +654,7 @@ def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
                 lp, _scale(h, cfg.attn_in_multiplier), cfg, positions,
                 window_dyn=window_dyn,
                 kv_cache=None if cache is None else (cache["k"], cache["v"]),
-                cache_pos=cache_pos)
+                cache_pos=cache_pos, layer=layer)
         if cache is not None:
             new_cache.update(k=kv[0], v=kv[1])
         y_attn = _scale(y_attn, cfg.attn_out_multiplier)
@@ -637,10 +670,11 @@ def decoder_layer(lp, x, cfg: ArchConfig, positions, *, is_global=None,
             k = jnp.einsum("bsd,dhe->bshe", enc_out, lp["xwk"])
             v = jnp.einsum("bsd,dhe->bshe", enc_out, lp["xwv"])
             if cache is not None and "xk" in cache:
-                new_cache.update(xk=k.astype(cache["xk"].dtype),
-                                 xv=v.astype(cache["xv"].dtype))
+                new_cache.update(xk=_store(cache["xk"], k, layer),
+                                 xv=_store(cache["xv"], v, layer))
         else:
-            k, v = cache["xk"].astype(q.dtype), cache["xv"].astype(q.dtype)
+            k, v = (_layer_of(cache[n], layer).astype(q.dtype)
+                    for n in ("xk", "xv"))
             new_cache.update(xk=cache["xk"], xv=cache["xv"])
         epos = jnp.broadcast_to(
             jnp.arange(k.shape[1], dtype=jnp.int32)[None],
@@ -879,7 +913,13 @@ def serve_step(params, cfg: ArchConfig, cache, tokens, pos, enc_out=None):
     """One decode step.  tokens: (B,) int32; pos: scalar int32 (current
     length, uniform across the batch) or (B,) int32 vector of PER-SLOT
     lengths -- the continuous-batching contract, where recycled slots sit at
-    independent generation depths.  Returns (logits (B, V), new_cache)."""
+    independent generation depths.  Returns (logits (B, V), new_cache).
+
+    The stacked cache rides in the layer scan's carry: layer l writes only
+    its new K/V rows at [l, b, pos[b]] and its SSM/conv state at [l], and
+    reads its own slice for attention; the cross-attention K/V pass through
+    untouched.  A donated cache is therefore updated in place, with no
+    temporary the size of a stack."""
     x = _scale(jnp.take(params["embed"], tokens[:, None], axis=0),
                cfg.embedding_multiplier)
     b = x.shape[0]
@@ -889,14 +929,18 @@ def serve_step(params, cfg: ArchConfig, cache, tokens, pos, enc_out=None):
         positions = pos.astype(jnp.int32)[:, None]
     flags = _global_flags(cfg)
 
-    def body(h, xs):
-        lp, lcache, is_global = xs
-        h2, new_cache, _ = decoder_layer(lp, h, cfg, positions,
-                                         is_global=is_global, enc_out=enc_out,
-                                         cache=lcache, cache_pos=pos)
-        return h2, new_cache
+    def body(carry, xs):
+        h, stacked = carry
+        lp, is_global, layer = xs
+        h2, stacked, _ = decoder_layer(lp, h, cfg, positions,
+                                       is_global=is_global, enc_out=enc_out,
+                                       cache=stacked, cache_pos=pos,
+                                       layer=layer)
+        return (h2, stacked), None
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache, flags))
+    (x, new_cache), _ = jax.lax.scan(
+        body, (x, cache),
+        (params["layers"], flags, jnp.arange(cfg.num_layers, dtype=jnp.int32)))
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return _logits(params, cfg, x)[:, 0], new_cache
 

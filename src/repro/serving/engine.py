@@ -44,7 +44,8 @@ The jitted step functions live at MODULE level, keyed on the static
 test constructing one -- shares one compile cache, the ``_fused_step``
 idiom from ``train/source.py``.  The prefill (which scatters its prompt's
 cache into the live one) and the decode step donate the live cache, so it
-is updated in place.
+is updated in place: the decode step carries it through its layer scan and
+writes only each slot's new K/V row and each layer's SSM/conv state.
 """
 from __future__ import annotations
 

@@ -82,6 +82,42 @@ def test_decode_matches_forward(arch):
     assert max(errs) < tol, f"decode diverges from forward: {max(errs)}"
 
 
+@pytest.mark.parametrize("per_slot", [True, False],
+                         ids=["vector_pos", "scalar_pos"])
+@pytest.mark.parametrize("arch", ["internlm2-1.8b", "mamba2-130m",
+                                  "hymba-1.5b", "qwen3-moe-30b-a3b",
+                                  "falcon-h1-34b"])
+def test_decode_step_writes_only_new_rows(arch, per_slot):
+    """A decode step changes only the K/V rows [l, b, pos[b]] of every layer
+    and each layer's conv/SSM state; every other cache entry is returned
+    bit for bit, whichever cache leaves the arch has."""
+    cfg = reduced_config(arch)
+    params = lm.init_lm(KEY, cfg)
+    b, max_seq = 3, 16
+    pos = (jnp.asarray([3, 9, max_seq - 1], jnp.int32) if per_slot
+           else jnp.int32(max_seq - 1))
+    shapes = lm.init_cache(cfg, b, max_seq, dtype=jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(shapes))
+    cache = {n: jax.random.normal(k, c.shape, c.dtype)
+             for k, (n, c) in zip(keys, sorted(shapes.items()))}
+    toks = jax.random.randint(jax.random.PRNGKey(6), (b,), 0, cfg.vocab_size)
+    logits, new = jax.jit(lambda p, c, t, q: lm.serve_step(p, cfg, c, t, q))(
+        params, cache, toks, pos)
+    assert bool(jnp.isfinite(logits).all())
+    assert set(new) == set(cache)
+    rows = np.zeros((cfg.num_layers, b, max_seq), bool)
+    rows[:, np.arange(b), np.broadcast_to(np.asarray(pos), (b,))] = True
+    for name in cache:
+        old, got = np.asarray(cache[name]), np.asarray(new[name])
+        assert got.shape == old.shape and got.dtype == old.dtype, name
+        if name in ("k", "v"):
+            np.testing.assert_array_equal(got[~rows], old[~rows], name)
+            assert (got[rows] != old[rows]).any(axis=(1, 2)).all(), name
+        else:               # recurrent state: every layer and slot rewritten
+            assert (got != old).reshape(cfg.num_layers, b, -1).any(-1).all(), \
+                name
+
+
 def test_prefill_matches_forward():
     cfg = dataclasses.replace(reduced_config("internlm2-1.8b"), attn_chunk=16)
     params = lm.init_lm(KEY, cfg)

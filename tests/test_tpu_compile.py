@@ -129,8 +129,8 @@ def test_falcon_h1_stage_serves_on_one_chip(one_chip):
     """The ``serve-falcon-h1`` cell's programs at their timed shapes (one
     8-layer stage at published widths, 16 slots of 5,120 positions, the
     largest prefill bucket) compile for one v5e; both update the donated
-    cache in place, and the decode step, with the weights and cache it is
-    handed, fits the chip's 16 GiB."""
+    cache in place, and the decode step holds no stack-sized temporary and,
+    with the weights and cache it is handed, fits the chip's 16 GiB."""
     import json
     import os
     from bench.loops import lm_serve
@@ -161,6 +161,9 @@ def test_falcon_h1_stage_serves_on_one_chip(one_chip):
     assert decode.alias_size_in_bytes >= cache_bytes
     assert decode.argument_size_in_bytes + decode.temp_size_in_bytes < \
         15 * 2 ** 30
+    # the stacked cache rides the layer scan's carry and only the new rows
+    # are written: no temporary near the 1.34 GB of the K or the V stack
+    assert decode.temp_size_in_bytes < 2 ** 28
     one = _spec((1,), jnp.int32, one_chip)
     prefill = engine._prefill.lower(
         params, cfg, cache, _spec((1, longest), jnp.int32, one_chip), one,
