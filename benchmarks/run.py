@@ -36,7 +36,6 @@ MODULES = [
     "benchmarks.loading_throughput",   # Fig. 11
     "benchmarks.datagen_throughput",   # streaming produce: seq vs overlapped
     "benchmarks.epoch_time",           # Fig. 12 (+ device-resident row)
-    "benchmarks.kernel_throughput",    # decompression-overhead substrate
     "benchmarks.serving_throughput",   # continuous batching vs lockstep
     "benchmarks.checkpoint_io",        # codec-founded lossy checkpoints
     "benchmarks.roofline",             # §Roofline table (dry-run artifacts)

@@ -1,57 +1,15 @@
 """Paper Algorithm 1 / Table: per-sample tolerance search statistics.
 
 Runs Algorithm 1 over a set of samples and reports iterations-to-converge
-(paper: 1-2), realized ratios, and the compression-vs-model error margin,
-plus a fused-vs-baseline pairing: the search loop body either runs the full
-encode->pack->unpack->decode roundtrip (baseline) or the stats-only path
-that hoists quantize/transform out of the while_loop and skips plane
-packing entirely (fused; bit-identical decisions, tests assert so).
-
-``--smoke`` runs a study-free seconds-scale pairing and gates the fused
-speedup at >= 1.3x (one retry absorbs a noisy box), writing
-``BENCH_tolerance_search.json`` for the CI artifact trail.
+(paper: 1-2), realized ratios, and the compression-vs-model error margin.
 """
 from __future__ import annotations
 
-import argparse
 import time
 
 import numpy as np
 
 from repro.core import algorithm1_per_sample, find_tolerance_batch
-
-SPEEDUP_GATE = 1.3
-
-
-def _pair_rows(xs, errs, tag: str, reps: int):
-    """Time fused vs baseline search on one stack (both pre-compiled)."""
-    find_tolerance_batch(xs, errs, fused=True)        # compile
-    find_tolerance_batch(xs, errs, fused=False)
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        find_tolerance_batch(xs, errs, fused=True)
-    fused_s = (time.perf_counter() - t0) / reps
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        find_tolerance_batch(xs, errs, fused=False)
-    base_s = (time.perf_counter() - t0) / reps
-    speedup = base_s / max(fused_s, 1e-9)
-    n = len(xs)
-    return [
-        (f"{tag}/fused", fused_s * 1e6 / n,
-         f"samples={n} total_ms={fused_s * 1e3:.1f}"),
-        (f"{tag}/baseline", base_s * 1e6 / n,
-         f"samples={n} total_ms={base_s * 1e3:.1f} "
-         f"speedup={speedup:.2f}x "
-         f"{'(>=' if speedup >= SPEEDUP_GATE else '(UNDER '}"
-         f"{SPEEDUP_GATE}x)"),
-    ]
-
-
-def _under_gate(rows):
-    """Names of pairing rows whose fused speedup fell under the gate."""
-    return [name for name, _, derived in rows
-            if "speedup=" in derived and "(UNDER" in derived]
 
 
 def run():
@@ -91,45 +49,9 @@ def run():
          f"speedup={dt / max(dt_batch, 1e-9):.1f}x "
          f"max_doubling_steps_off={off_by.max():.2f}"),
     ]
-    rows += _pair_rows(batch, np.asarray(errs, np.float32),
-                       "alg1/search32", reps=3)
     return rows
 
 
-def run_smoke():
-    """Study-free pairing on synthetic fields (seconds-scale CI lane)."""
-    rng = np.random.default_rng(0)
-    t = np.linspace(0, 1, 64)
-    xx, yy = np.meshgrid(t, t)
-    base = np.sin(6 * xx + 2 * yy) + 0.3 * np.cos(14 * yy * xx)
-    xs = np.stack([(base * (1 + 0.1 * i)
-                    + 0.05 * rng.standard_normal((64, 64))).astype(np.float32)
-                   for i in range(24)])
-    errs = np.full(24, 0.01, np.float32)
-    return _pair_rows(xs, errs, "alg1/smoke24", reps=5)
-
-
 if __name__ == "__main__":
-    ap = argparse.ArgumentParser()
-    ap.add_argument("--smoke", action="store_true",
-                    help="study-free fused-vs-baseline pairing; exits "
-                         f"non-zero if the fused search stays under "
-                         f"{SPEEDUP_GATE}x the roundtrip baseline")
-    args = ap.parse_args()
-    t_start = time.time()
-    rows = run_smoke() if args.smoke else run()
-    if args.smoke and _under_gate(rows):
-        rows = run_smoke()                   # one retry absorbs a noisy box
-    for r in rows:
+    for r in run():
         print(",".join(map(str, r)))
-    if args.smoke:
-        under = _under_gate(rows)
-        from benchmarks.run import env_provenance, write_bench_json
-        write_bench_json("benchmarks.tolerance_search", rows,
-                         time.time() - t_start, "fail" if under else "ok",
-                         env=env_provenance())
-        if under:
-            raise SystemExit(
-                f"fused tolerance search under {SPEEDUP_GATE}x baseline for "
-                f"{under}: the stats-only loop body is no longer skipping "
-                "the pack/unpack roundtrip")

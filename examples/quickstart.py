@@ -26,7 +26,7 @@ def main():
 
     print("== 2. error-bounded compression")
     sample = jnp.asarray(np.transpose(fields[5], (2, 0, 1)))
-    codec = get_codec("fixed_accuracy", backend="jnp")
+    codec = get_codec("fixed_accuracy")
     for tol in (1e-1, 1e-2):
         cf = codec.encode_batch(sample[None], jnp.asarray([tol], jnp.float32))
         err = float(jnp.max(jnp.abs(codec.decode_batch(cf)[0] - sample)))

@@ -10,22 +10,27 @@ is the single seam instead:
   Codec.decode_batch(cf)               -> (N, ...) float32
   Codec.nbytes(cf)                     -> (N,) logical bytes
 
-Two codecs, each with a pure-jnp reference backend and a Pallas kernel
-backend behind one registry:
+Codecs behind one registry:
 
-  get_codec("fixed_accuracy", tolerance=1e-3)                  # error-bounded
-  get_codec("fixed_rate", bits_per_value=12, backend="pallas") # uniform rate
+  get_codec("fixed_accuracy", tolerance=1e-3)      # error-bounded
+  get_codec("fixed_rate", bits_per_value=12)       # uniform rate
 
 Codec instances are frozen dataclasses — hashable, so they can ride through
 ``jax.jit`` static arguments — and every method is jit-traceable: the fused
 gather→decode train step (repro.train.source) traces ``decode_stacked_payloads``
-directly into the compiled step.  Both backends are bit-identical (asserted
-in tests); ``backend="pallas"`` routes the kernels in repro.kernels, which
-themselves fall back to a compiled-jnp oracle off-TPU.
+directly into the compiled step.
+
+This is the only module that routes to the kernels.  The default
+``backend="pallas"`` runs the entries of ``repro.kernels.ops``, which pick
+the compiled Pallas kernel on a TPU and its jitted jnp oracle anywhere else.
+``backend="jnp"`` runs the pure-jnp reference codec of compression/zfp.py,
+which tests and the benchmark's reference checks compare the kernels
+against, bit for bit.
 """
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Dict, Mapping, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
@@ -35,8 +40,7 @@ import numpy as np
 from repro.compression import transform as T
 from repro.compression.zfp import (
     CompressedField, compressed_nbytes_batch, decode_batch as _decode_batch_jnp,
-    encode_fixed_accuracy_batch, encode_fixed_rate_batch, fa_precompute_batch,
-    fa_stats_batch, trim_to_nplanes,
+    encode_fixed_accuracy_batch, encode_fixed_rate_batch, trim_to_nplanes,
 )
 from repro.obs import trace as obs_trace
 
@@ -85,15 +89,15 @@ def decode_stacked_payloads(payload, emax, padded_shape, shape,
     their bit-exactness contract rides on this being one function.  Accepts
     numpy or jax arrays and is jit-traceable.
     """
-    from repro.kernels import ops                    # lazy: ops imports zfp
+    from repro.kernels import ops        # lazy: kernels rank above compression
     b, nb, wmax = payload.shape
     flat_p = jnp.reshape(jnp.asarray(payload), (b * nb, wmax))
     flat_e = jnp.reshape(jnp.asarray(emax), (b * nb,))
     if nplanes is None:
-        blocks = ops.zfp_decode_blocks_fast(flat_p, flat_e, 2 * wmax)
+        blocks = ops.zfp_decode_blocks(flat_p, flat_e, 2 * wmax)
     else:
         flat_n = jnp.reshape(jnp.asarray(nplanes), (b * nb,))
-        blocks = ops.zfp_decode_blocks_fa_fast(flat_p, flat_e, flat_n)
+        blocks = ops.zfp_decode_blocks_fa(flat_p, flat_e, flat_n)
     batch = T.deblockify(blocks, (b,) + tuple(padded_shape))
     return batch[(slice(None),) + tuple(slice(0, s) for s in shape)]
 
@@ -102,6 +106,40 @@ def _decode_batch_kernel(cf: CompressedField) -> jnp.ndarray:
     """Kernel-path batched decode of a (N, ...)-leaved CompressedField."""
     return decode_stacked_payloads(cf.payload, cf.emax, cf.padded_shape,
                                    cf.shape, nplanes=cf.nplanes)
+
+
+@jax.jit
+def _encode_fa_kernel(xs: jnp.ndarray, tols: jnp.ndarray) -> CompressedField:
+    """Kernel-path fixed-accuracy encode of a (N, ...) stack at (N,) L-inf
+    tolerances.  The padded stack goes to the kernel coefficient-major,
+    (16, N*nb) from one transpose, so it lays the blocks along its 128
+    lanes; no (nb, 16) array is built."""
+    from repro.kernels import ops        # lazy: kernels rank above compression
+    tols = jnp.asarray(tols, jnp.float32)
+    n = xs.shape[0]
+    xp = T.pad_to_blocks(xs.astype(jnp.float32))
+    coefs = T.blockify_coef_major(xp)                # (16, N * nb)
+    nb = coefs.shape[1] // n
+    payload, emax, nplanes = ops.zfp_encode_blocks_fa(
+        coefs, jnp.repeat(tols, nb))
+    return CompressedField(payload.reshape(n, nb, -1), emax.reshape(n, nb),
+                           nplanes.reshape(n, nb), xs.shape[1:], xp.shape[1:])
+
+
+@partial(jax.jit, static_argnames=("bits_per_value",))
+def _encode_fr_kernel(xs: jnp.ndarray, bits_per_value: int) -> CompressedField:
+    """Kernel-path fixed-rate encode: all N samples' blocks go to the kernel
+    as one (N*nb, 16) grid, so it tiles a single long block axis."""
+    assert 0 < bits_per_value <= T.TOTAL_PLANES
+    from repro.kernels import ops        # lazy: kernels rank above compression
+    n = xs.shape[0]
+    xp = T.pad_to_blocks(xs.astype(jnp.float32))
+    blocks = T.blockify(xp)                          # (N * nb, 16)
+    payload, emax = ops.zfp_encode_blocks(blocks, bits_per_value)
+    nb = blocks.shape[0] // n
+    nplanes = jnp.full((n, nb), bits_per_value, dtype=jnp.int32)
+    return CompressedField(payload.reshape(n, nb, -1), emax.reshape(n, nb),
+                           nplanes, xs.shape[1:], xp.shape[1:])
 
 
 def _pad4(shape2d) -> Tuple[int, ...]:
@@ -151,11 +189,11 @@ class FixedAccuracyCodec:
                 raise ValueError("fixed_accuracy encode needs per-sample "
                                  "tolerances or a codec-level default")
             tolerances = jnp.full((xs.shape[0],), self.tolerance, jnp.float32)
+        encode = (_encode_fa_kernel if self.backend == "pallas"
+                  else encode_fixed_accuracy_batch)
         with obs_trace.span("codec.encode_batch", cat="codec",
                             samples=int(xs.shape[0])):
-            return encode_fixed_accuracy_batch(
-                xs, jnp.asarray(tolerances, jnp.float32),
-                use_pallas=self.backend == "pallas")
+            return encode(xs, jnp.asarray(tolerances, jnp.float32))
 
     def decode_batch(self, cf: CompressedField) -> jnp.ndarray:
         if self.backend == "pallas":
@@ -165,14 +203,6 @@ class FixedAccuracyCodec:
     def nbytes(self, cf: CompressedField) -> jnp.ndarray:
         return compressed_nbytes_batch(cf, mode="fixed_accuracy")
 
-    # stats-only roundtrip for Algorithm 1's search body: precompute the
-    # tolerance-independent encode state once, then evaluate (L1, nbytes)
-    # per candidate tolerance with no plane packing/unpacking (pure jnp on
-    # both backends — the reductions dominate and XLA fuses them; the Pallas
-    # encode kernel packs only the final accepted tolerance)
-    precompute = staticmethod(fa_precompute_batch)
-    stats = staticmethod(fa_stats_batch)
-
     field_to_arrays = staticmethod(_cf_to_arrays)
     field_from_arrays = staticmethod(_cf_from_arrays)
 
@@ -181,7 +211,7 @@ class FixedAccuracyCodec:
 class FixedRateCodec:
     """Uniform bits-per-value mode (dense payload, no per-block headers)."""
     bits_per_value: int = 12
-    backend: str = "jnp"
+    backend: str = "pallas"
 
     @property
     def name(self) -> str:
@@ -189,8 +219,9 @@ class FixedRateCodec:
 
     def encode_batch(self, xs, tolerances=None) -> CompressedField:
         del tolerances                   # rate is fixed; no error bound
-        return encode_fixed_rate_batch(xs, self.bits_per_value,
-                                       use_pallas=self.backend == "pallas")
+        encode = (_encode_fr_kernel if self.backend == "pallas"
+                  else encode_fixed_rate_batch)
+        return encode(xs, self.bits_per_value)
 
     def decode_batch(self, cf: CompressedField) -> jnp.ndarray:
         if self.backend == "pallas":
@@ -350,8 +381,8 @@ def codec_names() -> list:
 
 def get_codec(name: str, *, backend: str = "pallas", **params) -> Codec:
     """Instantiate a registered codec: ``get_codec("fixed_accuracy",
-    tolerance=1e-3)``.  ``backend`` selects "jnp" (pure reference) or
-    "pallas" (kernel path; compiled-oracle fallback off-TPU)."""
+    tolerance=1e-3)``.  ``backend`` selects "pallas" (the kernels; their
+    compiled jnp oracles off the TPU) or "jnp" (the pure-jnp reference)."""
     if name not in _REGISTRY:
         raise KeyError(f"unknown codec {name!r}; registered: {codec_names()}")
     if backend not in BACKENDS:
@@ -372,25 +403,18 @@ def codec_spec(codec: Codec) -> dict:
     return {"name": codec.name, "backend": backend, "params": params}
 
 
-def codec_from_spec(spec: Mapping[str, Any],
-                    backend: Optional[str] = None) -> Codec:
-    """Inverse of :func:`codec_spec`; ``backend`` overrides the recorded one
-    (e.g. restore a jnp-encoded checkpoint through the Pallas decode path)."""
-    return get_codec(spec["name"], backend=backend or spec["backend"],
-                     **spec["params"])
+def codec_from_spec(spec: Mapping[str, Any]) -> Codec:
+    """Inverse of :func:`codec_spec`."""
+    return get_codec(spec["name"], backend=spec["backend"], **spec["params"])
 
 
 def codec_from_plan(codec_plan) -> Codec:
     """Codec for a datagen ``CodecPlan``-shaped object (duck-typed: ``mode``
-    plus the mode's parameters), preserving the plan's backend choice."""
+    plus the mode's parameters)."""
     if codec_plan.mode == "fixed_accuracy":
-        backend = "pallas" if getattr(codec_plan, "use_pallas", False) else "jnp"
-        return get_codec("fixed_accuracy", tolerance=codec_plan.tolerance,
-                         backend=backend)
+        return get_codec("fixed_accuracy", tolerance=codec_plan.tolerance)
     if codec_plan.mode == "fixed_rate":
-        backend = "pallas" if getattr(codec_plan, "use_pallas", False) else "jnp"
-        return get_codec("fixed_rate", bits_per_value=codec_plan.bits_per_value,
-                         backend=backend)
+        return get_codec("fixed_rate", bits_per_value=codec_plan.bits_per_value)
     raise ValueError(f"unknown codec mode {codec_plan.mode!r}")
 
 
@@ -454,9 +478,9 @@ class TreeCodecMeta:
     codec: Tuple
     leaves: Tuple[LeafSpec, ...]
 
-    def make_codec(self, backend: Optional[str] = None) -> Codec:
-        name, rec_backend, params = self.codec
-        return get_codec(name, backend=backend or rec_backend, **dict(params))
+    def make_codec(self) -> Codec:
+        name, backend, params = self.codec
+        return get_codec(name, backend=backend, **dict(params))
 
     def to_json(self) -> dict:
         name, backend, params = self.codec

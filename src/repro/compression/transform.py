@@ -26,6 +26,10 @@ Q_FIXED_POINT = 28
 TOTAL_PLANES = 30
 # int32 words per block at full precision (2 planes of 16 lanes per word).
 MAX_WORDS = (TOTAL_PLANES + 1) // 2
+# Fixed-accuracy plane count: an optimistic guess of emax - floor(log2(tol))
+# + GUARD_BITS, then at most MAX_FIX_ITERS correction steps of +2 planes.
+GUARD_BITS = 2
+MAX_FIX_ITERS = 6
 
 _NEG_MASK = jnp.int32(-1431655766)  # 0xAAAAAAAA as int32 bit pattern
 

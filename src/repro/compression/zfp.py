@@ -1,4 +1,7 @@
-"""TPU-adapted ZFP codec: fixed-rate and error-bounded fixed-accuracy modes.
+"""Pure-jnp reference ZFP codec: fixed-rate and error-bounded fixed-accuracy.
+
+``backend="jnp"`` codecs (compression/api.py) run it, and tests hold the
+Pallas kernels in repro.kernels bit-identical to it.
 
 Layout differences vs CPU ZFP (see DESIGN.md §3): bit planes are packed two
 per int32 word at deterministic per-block offsets (no group testing, no
@@ -21,9 +24,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compression import transform as T
-
-GUARD_BITS = 2          # optimistic initial guess; correction loop enforces bound
-MAX_FIX_ITERS = 6
 
 
 @jax.tree_util.register_pytree_node_class
@@ -94,35 +94,18 @@ def decode_fixed_rate(cf: CompressedField) -> jnp.ndarray:
     return _crop(xp, cf.shape)
 
 
-@partial(jax.jit, static_argnames=("bits_per_value", "use_pallas"))
-def encode_fixed_rate_batch(xs: jnp.ndarray, bits_per_value: int,
-                            use_pallas: bool = False) -> CompressedField:
+@partial(jax.jit, static_argnames=("bits_per_value",))
+def encode_fixed_rate_batch(xs: jnp.ndarray,
+                            bits_per_value: int) -> CompressedField:
     """Batched fixed-rate encode: one compiled call for a whole (N, ...) stack.
 
     Returns a CompressedField whose array leaves carry a leading batch axis
     (payload (N, nb, W), emax/nplanes (N, nb)); ``shape``/``padded_shape``
     describe a single sample, matching ``encode_fixed_accuracy_batch``.
-
-    ``use_pallas=True`` routes the per-block transform + plane packing
-    through the Pallas TPU encode kernel (``kernels/zfp_codec.py``; interpret
-    mode off-TPU): all N samples' blocks are flattened into one (N*nb, 16)
-    grid so the kernel tiles a single long block axis.  Both paths produce
-    bit-identical payload/emax words (asserted in tests/test_compression.py
-    against the pure-jnp encoder).
     """
     assert 0 < bits_per_value <= T.TOTAL_PLANES
-    if not use_pallas:
-        return jax.vmap(lambda x: encode_fixed_rate(x, bits_per_value))(
-            xs.astype(jnp.float32))
-    from repro.kernels import ops                    # lazy: ops imports zfp
-    n = xs.shape[0]
-    xp = T.pad_to_blocks(xs.astype(jnp.float32))
-    blocks = T.blockify(xp)                          # (N * nb, 16)
-    payload, emax = ops.zfp_encode_blocks(blocks, bits_per_value)
-    nb = blocks.shape[0] // n
-    nplanes = jnp.full((n, nb), bits_per_value, dtype=jnp.int32)
-    return CompressedField(payload.reshape(n, nb, -1), emax.reshape(n, nb),
-                           nplanes, xs.shape[1:], xp.shape[1:])
+    return jax.vmap(lambda x: encode_fixed_rate(x, bits_per_value))(
+        xs.astype(jnp.float32))
 
 
 # ---------------------------------------------------------------------------
@@ -130,7 +113,7 @@ def encode_fixed_rate_batch(xs: jnp.ndarray, bits_per_value: int,
 # ---------------------------------------------------------------------------
 
 def _planes_for_tolerance(emax: jnp.ndarray, tol: jnp.ndarray) -> jnp.ndarray:
-    b = emax - T.floor_log2(tol) + GUARD_BITS
+    b = emax - T.floor_log2(tol) + T.GUARD_BITS
     return jnp.clip(b, 0, T.TOTAL_PLANES).astype(jnp.int32)
 
 
@@ -159,7 +142,7 @@ def encode_fixed_accuracy(x: jnp.ndarray, tol: float) -> CompressedField:
     def cond(state):
         npl, it = state
         bad = (block_err(npl) > tol) & (npl < T.TOTAL_PLANES)
-        return jnp.any(bad) & (it < MAX_FIX_ITERS)
+        return jnp.any(bad) & (it < T.MAX_FIX_ITERS)
 
     def body(state):
         npl, it = state
@@ -173,9 +156,9 @@ def encode_fixed_accuracy(x: jnp.ndarray, tol: float) -> CompressedField:
     return CompressedField(payload, emax, nplanes, shape, xp.shape)
 
 
-@partial(jax.jit, static_argnames=("use_pallas",))
-def encode_fixed_accuracy_batch(xs: jnp.ndarray, tols: jnp.ndarray,
-                                use_pallas: bool = False) -> CompressedField:
+@jax.jit
+def encode_fixed_accuracy_batch(xs: jnp.ndarray,
+                                tols: jnp.ndarray) -> CompressedField:
     """Batched error-bounded encode: one compiled call for a whole stack.
 
     xs   : (N, ...) float array, compression over the trailing two dims
@@ -186,30 +169,9 @@ def encode_fixed_accuracy_batch(xs: jnp.ndarray, tols: jnp.ndarray,
     ``padded_shape`` describe a single sample.  Per-sample results are
     bit-identical to :func:`encode_fixed_accuracy` — the vmapped while_loop
     runs the same correction arithmetic under a per-sample active mask.
-
-    ``use_pallas=True`` routes the whole per-block pipeline (quantize →
-    lift → negabinary → plane guess → bound-verification correction →
-    variable-plane pack) through the Pallas fixed-accuracy encode kernel
-    (``kernels/zfp_codec.py``; compiled-jnp oracle off-TPU).  The padded
-    stack goes to it coefficient-major, (16, N*nb) from one transpose
-    (``T.blockify_coef_major``), so the kernel lays the blocks along its
-    128 lanes; no (nb, 16) array is built on this path.  Both paths emit
-    bit-identical (payload, emax, nplanes) — the static in-VMEM correction
-    loop is iteration-for-iteration the same arithmetic as the while_loop
-    above (asserted in tests/test_compression.py and tests/test_kernels.py).
     """
     tols = jnp.asarray(tols, jnp.float32)
-    if not use_pallas:
-        return jax.vmap(encode_fixed_accuracy)(xs.astype(jnp.float32), tols)
-    from repro.kernels import ops                    # lazy: ops imports zfp
-    n = xs.shape[0]
-    xp = T.pad_to_blocks(xs.astype(jnp.float32))
-    coefs = T.blockify_coef_major(xp)                # (16, N * nb)
-    nb = coefs.shape[1] // n
-    payload, emax, nplanes = ops.zfp_encode_coefs_fa_fast(
-        coefs, jnp.repeat(tols, nb))
-    return CompressedField(payload.reshape(n, nb, -1), emax.reshape(n, nb),
-                           nplanes.reshape(n, nb), xs.shape[1:], xp.shape[1:])
+    return jax.vmap(encode_fixed_accuracy)(xs.astype(jnp.float32), tols)
 
 
 @jax.jit
@@ -365,7 +327,7 @@ def fa_plane_counts(state: FAEncodeState, tols: jnp.ndarray) -> jnp.ndarray:
     def cond(s):
         npl, it = s
         bad = (block_err(npl) > tols_b) & (npl < T.TOTAL_PLANES)
-        return jnp.any(bad) & (it < MAX_FIX_ITERS)
+        return jnp.any(bad) & (it < T.MAX_FIX_ITERS)
 
     def body(s):
         npl, it = s

@@ -39,7 +39,7 @@ def as_codec(codec: CodecLike) -> Codec:
     """Resolve the historical ``bits`` shorthand: an int means the fixed-rate
     codec at that many bit planes; anything else must already be a Codec."""
     if isinstance(codec, int):
-        return get_codec("fixed_rate", bits_per_value=codec, backend="jnp")
+        return get_codec("fixed_rate", bits_per_value=codec)
     return codec
 
 

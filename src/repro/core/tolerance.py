@@ -25,14 +25,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compression import FixedAccuracyCodec
+from repro.compression import fa_precompute_batch, fa_stats_batch, get_codec
 from repro.obs import trace as obs_trace
 
 C_D = {1: 1.044, 2: 1.089, 3: 1.134, 4: 1.178}   # Fox & Lindstrom, Appendix A
-
-# The search's inner encode/decode runs through the unified Codec seam; the
-# frozen (hashable) instance rides into the jitted search as a static arg.
-_SEARCH_CODEC = FixedAccuracyCodec(backend="jnp")
 
 
 @dataclasses.dataclass
@@ -53,13 +49,13 @@ def find_tolerance(sample: np.ndarray, model_l1_error: float,
     """
     e = float(model_l1_error)
     x = jnp.asarray(sample, jnp.float32)
+    codec = get_codec("fixed_accuracy", backend="jnp")
 
     def roundtrip(t):
-        cf = _SEARCH_CODEC.encode_batch(x[None],
-                                        jnp.asarray([t], jnp.float32))
-        xd = _SEARCH_CODEC.decode_batch(cf)[0]
+        cf = codec.encode_batch(x[None], jnp.asarray([t], jnp.float32))
+        xd = codec.decode_batch(cf)[0]
         l1 = float(jnp.mean(jnp.abs(xd - x)))
-        return l1, float(x.size * 4 / int(np.asarray(_SEARCH_CODEC.nbytes(cf))[0]))
+        return l1, float(x.size * 4 / int(np.asarray(codec.nbytes(cf))[0]))
 
     t = (4.0 ** d) * e / C_D[d]
     best = None
@@ -121,11 +117,8 @@ class BatchToleranceResult:
                 for i in range(len(self))]
 
 
-@partial(jax.jit, static_argnames=("d", "max_iters", "codec", "fused"))
-def _search_batch(xs: jnp.ndarray, es: jnp.ndarray,
-                  d: int, max_iters: int,
-                  codec: FixedAccuracyCodec = _SEARCH_CODEC,
-                  fused: bool = True):
+@partial(jax.jit, static_argnames=("d", "max_iters"))
+def _search_batch(xs: jnp.ndarray, es: jnp.ndarray, d: int, max_iters: int):
     """Doubling/halving searches for all samples in one lax.while_loop.
 
     Per-sample masks replicate the reference control flow: double while the
@@ -135,32 +128,21 @@ def _search_batch(xs: jnp.ndarray, es: jnp.ndarray,
     one batched encode/decode; finished samples are masked out of the state
     updates, so results match find_tolerance exactly.
 
-    ``fused=True`` (default) swaps the loop body's full encode→pack→
-    unpack→decode roundtrip for the stats-only path: quantize / forward
-    lift / negabinary are hoisted out of the while_loop once
-    (``codec.precompute``), and each iteration only re-derives per-block
-    plane counts and the truncated decode (``codec.stats``) — the loop
-    needs nothing but per-sample L1 and byte counts, and pack(MAX_WORDS)
-    →unpack is an exact inverse, so the decision sequence is bit-identical
-    to the unfused baseline (tests assert so).
+    The loop body is stats-only: quantize / forward lift / negabinary are
+    hoisted out of the while_loop once (``fa_precompute_batch``), and each
+    iteration only re-derives per-block plane counts and the truncated
+    decode (``fa_stats_batch``) — the loop needs nothing but per-sample L1
+    and byte counts, and pack(MAX_WORDS)→unpack is an exact inverse, so the
+    decisions are those of find_tolerance's full encode→decode roundtrip
+    (tests assert so).
     """
     n = xs.shape[0]
     sample_size = int(np.prod(xs.shape[1:]))
-    axes = tuple(range(1, xs.ndim))
+    state = fa_precompute_batch(xs)
 
-    if fused:
-        state = codec.precompute(xs)
-
-        def evaluate(t):
-            l1, nbytes = codec.stats(state, t)
-            return l1, sample_size * 4.0 / nbytes
-    else:
-        def evaluate(t):
-            cf = codec.encode_batch(xs, t)
-            xd = codec.decode_batch(cf)
-            l1 = jnp.mean(jnp.abs(xd - xs), axis=axes)
-            ratio = sample_size * 4.0 / codec.nbytes(cf)
-            return l1, ratio
+    def evaluate(t):
+        l1, nbytes = fa_stats_batch(state, t)
+        return l1, sample_size * 4.0 / nbytes
 
     init = {
         "t": (4.0 ** d) * es / C_D[d],
@@ -219,17 +201,14 @@ def _search_batch(xs: jnp.ndarray, es: jnp.ndarray,
 
 def find_tolerance_batch(samples: np.ndarray | Sequence[np.ndarray],
                          model_l1_errors: Sequence[float] | np.ndarray,
-                         d: int = 2, max_iters: int = 8,
-                         codec: FixedAccuracyCodec | None = None,
-                         fused: bool = True) -> BatchToleranceResult:
+                         d: int = 2,
+                         max_iters: int = 8) -> BatchToleranceResult:
     """Algorithm 1 for a stack of same-shape samples in one compiled call.
 
     Equivalent to ``[find_tolerance(s, e) for s, e in zip(...)]`` but the
-    whole search runs device-side: one jitted lax.while_loop whose body
-    evaluates every still-active sample with the batched codec.  ``fused``
-    selects the stats-only loop body (see ``_search_batch``); ``codec``
-    overrides the search codec (e.g. ``backend="pallas"`` on TPU for the
-    unfused roundtrip path).
+    whole search runs device-side: one jitted lax.while_loop whose
+    stats-only body evaluates every still-active sample (see
+    ``_search_batch``).
     """
     xs = jnp.asarray(np.stack([np.asarray(s, np.float32) for s in samples])
                      if not isinstance(samples, (np.ndarray, jnp.ndarray))
@@ -238,9 +217,7 @@ def find_tolerance_batch(samples: np.ndarray | Sequence[np.ndarray],
     assert xs.shape[0] == es.shape[0], "one model error per sample"
     with obs_trace.span("tolerance.search_batch", cat="certify",
                         samples=int(xs.shape[0])) as sp:
-        found = _search_batch(xs, es, d, max_iters,
-                              _SEARCH_CODEC if codec is None else codec,
-                              fused)
+        found = _search_batch(xs, es, d, max_iters)
         # the host waits here for the whole search to finish on the device
         with obs_trace.span("tolerance.readback", cat="certify"):
             tol, l1, ratio, iters = (np.asarray(a) for a in found)

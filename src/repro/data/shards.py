@@ -4,7 +4,7 @@ Per-sample files (``CompressedArrayStore`` with ``root=``) pay a file open +
 zip parse per sample per batch — the classic small-file problem that chunked
 container formats solve for lossy-compressed scientific data.  This module
 packs ``shard_size`` samples into each shard file and decodes a whole batch
-with a single ``zfp_decode_blocks_fast`` call.
+with a single ``decode_stacked_payloads`` call.
 
 On-disk layout (``root/``):
   manifest.json          -- format tag, sample/padded shapes, block count,

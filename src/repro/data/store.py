@@ -147,10 +147,9 @@ class CompressedArrayStore:
         if root is not None:
             os.makedirs(root, exist_ok=True)
         if tolerances is not None:
-            codec = get_codec("fixed_accuracy", backend="jnp")
+            codec = get_codec("fixed_accuracy")
         else:
-            codec = get_codec("fixed_rate", bits_per_value=bits_per_value,
-                              backend="jnp")
+            codec = get_codec("fixed_rate", bits_per_value=bits_per_value)
         for i, s in enumerate(samples):
             x = jnp.asarray(np.asarray(s, np.float32))
             tols = (None if tolerances is None

@@ -29,7 +29,6 @@ class CodecPlan:
     mode: str = "fixed_accuracy"
     tolerance: float = 1e-3          # fixed_accuracy: L-inf bound per sample
     bits_per_value: int = 12         # fixed_rate: uniform planes per value
-    use_pallas: bool = False         # Pallas encode kernel path (both modes)
 
     def validate(self) -> None:
         if self.mode not in CODEC_MODES:
@@ -41,15 +40,10 @@ class CodecPlan:
 
     def to_dict(self) -> dict:
         """Canonical form carrying only the fields that can change the
-        produced bytes.  ``use_pallas`` is excluded under fixed-accuracy:
-        the Pallas encode kernel is bit-identical to the jnp encoder
-        (tests assert payload/emax/nplanes equality), so flipping it must
-        not perturb the plan hash and refuse a resume of a byte-identical
-        dataset."""
+        produced bytes."""
         if self.mode == "fixed_accuracy":
             return {"mode": self.mode, "tolerance": self.tolerance}
-        return {"mode": self.mode, "bits_per_value": self.bits_per_value,
-                "use_pallas": self.use_pallas}
+        return {"mode": self.mode, "bits_per_value": self.bits_per_value}
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,27 +1,21 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public entries of the Pallas kernels: one per kernel.
 
-Dispatch policy: every wrapper chooses its path by the platform it is
-lowered for (``jax.lax.platform_dependent``), not by the process's default
-backend, so a program compiled for a TPU carries the compiled kernel even
-when the compile runs in a CPU process.
-
-  * plain wrappers (``zfp_decode_blocks`` ...): compiled Pallas on TPU,
-    the same kernel in interpret mode elsewhere -- the correctness path the
-    tests validate against ref.py;
-  * ``*_fast`` wrappers: compiled Pallas on TPU, the jitted jnp oracle
-    elsewhere (interpret mode runs the kernel body in Python, far too slow
-    for the training and datagen hot paths).  The oracle is bit-identical
-    to the kernel (tests assert so).
+Dispatch policy: every entry chooses its path by the platform it is lowered
+for (``jax.lax.platform_dependent``), not by the process's default backend,
+so a program compiled for a TPU carries the compiled kernel even when the
+compile runs in a CPU process.  On a TPU an entry runs the compiled Pallas
+kernel; anywhere else it runs the kernel's jitted jnp oracle from ref.py
+(interpret mode runs the kernel body in Python, far too slow for the
+training and datagen hot paths).  The oracle is bit-identical to the
+kernel: tests run the kernel bodies in interpret mode
+(``zfp_codec.<kernel>(..., interpret=True)``) against it.
 """
 from __future__ import annotations
 
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
-from repro.compression import transform as T
-from repro.compression.zfp import CompressedField
 from repro.kernels import ref
 from repro.kernels import zfp_codec
 from repro.kernels import flash_attention as _fa
@@ -33,13 +27,7 @@ def _kernel_on_tpu(kernel, other, *args):
 
 
 def zfp_decode_blocks(payload, emax, bits_per_value):
-    kernel = partial(zfp_codec.zfp_decode_blocks, bits_per_value=bits_per_value)
-    return _kernel_on_tpu(kernel, partial(kernel, interpret=True),
-                          payload, emax)
-
-
-def zfp_decode_blocks_fast(payload, emax, bits_per_value):
-    """Fixed-rate decode for throughput: kernel on TPU, oracle elsewhere."""
+    """Fixed-rate decode of (nb, W) payload words -> (nb, 16) f32 blocks."""
     return _kernel_on_tpu(
         partial(zfp_codec.zfp_decode_blocks, bits_per_value=bits_per_value),
         _ref_decode_jit, payload, emax)
@@ -51,15 +39,7 @@ def _ref_decode_jit(payload, emax):
 
 
 def zfp_decode_blocks_fa(payload, emax, nplanes):
-    """Fixed-accuracy decode (per-block variable plane counts), kernel path."""
-    return _kernel_on_tpu(
-        zfp_codec.zfp_decode_blocks_fa,
-        partial(zfp_codec.zfp_decode_blocks_fa, interpret=True),
-        payload, emax, nplanes)
-
-
-def zfp_decode_blocks_fa_fast(payload, emax, nplanes):
-    """Fixed-accuracy decode for throughput: kernel on TPU, oracle elsewhere.
+    """Fixed-accuracy decode (per-block plane counts) -> (nb, 16) f32.
 
     This is what the fused gather -> decode train step traces through.
     """
@@ -73,12 +53,7 @@ def _ref_decode_fa_jit(payload, emax, nplanes):
 
 
 def zfp_encode_blocks(blocks, bits_per_value):
-    kernel = partial(zfp_codec.zfp_encode_blocks, bits_per_value=bits_per_value)
-    return _kernel_on_tpu(kernel, partial(kernel, interpret=True), blocks)
-
-
-def zfp_encode_blocks_fast(blocks, bits_per_value):
-    """Fixed-rate encode for throughput: kernel on TPU, oracle elsewhere."""
+    """Fixed-rate encode of (nb, 16) f32 blocks -> (payload, emax)."""
     return _kernel_on_tpu(
         partial(zfp_codec.zfp_encode_blocks, bits_per_value=bits_per_value),
         partial(_ref_encode_jit, bits_per_value=bits_per_value), blocks)
@@ -89,29 +64,10 @@ def _ref_encode_jit(blocks, bits_per_value):
     return ref.zfp_encode_blocks_ref(blocks, bits_per_value)
 
 
-def zfp_encode_blocks_fa(blocks, tols):
-    """Fixed-accuracy encode of (nb, 16) blocks (per-block L-inf
-    tolerances), kernel path."""
-    return _kernel_on_tpu(
-        zfp_codec.zfp_encode_blocks_fa,
-        partial(zfp_codec.zfp_encode_blocks_fa, interpret=True),
-        blocks.T, tols)
-
-
-def zfp_encode_blocks_fa_fast(blocks, tols):
-    """Fixed-accuracy encode of (nb, 16) blocks for throughput: kernel on
-    TPU, oracle elsewhere (``zfp_encode_coefs_fa_fast`` of ``blocks.T``)."""
-    return zfp_encode_coefs_fa_fast(blocks.T, tols)
-
-
-def zfp_encode_coefs_fa_fast(coefs, tols):
+def zfp_encode_blocks_fa(coefs, tols):
     """Fixed-accuracy encode of coefficient-major (16, nb) blocks
-    (``transform.blockify_coef_major``): kernel on TPU, oracle elsewhere.
-
-    Bit-identical to the kernel path (tests assert payload/emax/nplanes
-    equality), so the codec seam's ``backend="pallas"`` encode and the
-    datagen encode-on-device path use it unconditionally.
-    """
+    (``transform.blockify_coef_major``) at (nb,) L-inf tolerances ->
+    (payload, emax, nplanes)."""
     return _kernel_on_tpu(zfp_codec.zfp_encode_blocks_fa, _ref_encode_fa_jit,
                           coefs, tols)
 
@@ -119,25 +75,6 @@ def zfp_encode_coefs_fa_fast(coefs, tols):
 @jax.jit
 def _ref_encode_fa_jit(coefs, tols):
     return ref.zfp_encode_blocks_fa_ref(coefs.T, tols)
-
-
-def decode_field(cf: CompressedField) -> jnp.ndarray:
-    """Kernel-path decode of a fixed-rate CompressedField."""
-    bits = int(cf.payload.shape[1]) * 2
-    blocks = zfp_decode_blocks(cf.payload, cf.emax, bits)
-    xp = T.deblockify(blocks, cf.padded_shape)
-    slices = tuple(slice(0, s) for s in cf.shape)
-    return xp[slices]
-
-
-def encode_field(x: jnp.ndarray, bits_per_value: int) -> CompressedField:
-    """Kernel-path fixed-rate encode of an array (trailing 2 dims blocked)."""
-    shape = x.shape
-    xp = T.pad_to_blocks(x.astype(jnp.float32))
-    blocks = T.blockify(xp)
-    payload, emax = zfp_encode_blocks(blocks, bits_per_value)
-    nplanes = jnp.full((blocks.shape[0],), bits_per_value, jnp.int32)
-    return CompressedField(payload, emax, nplanes, shape, xp.shape)
 
 
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, window=None):

@@ -52,12 +52,11 @@ def zfp_encode_blocks_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
     realized error is within tolerance, so the unroll reaches the identical
     fixpoint).
     """
-    from repro.compression.zfp import GUARD_BITS, MAX_FIX_ITERS
     emax = T.block_emax(blocks_f)
     qi = T.quantize_blocks(blocks_f, emax)
     u_full = T.int2nb(T.fwd_transform_2d(qi))
     tols = jnp.asarray(tols, jnp.float32)
-    npl = jnp.clip(emax - T.floor_log2(tols) + GUARD_BITS, 0,
+    npl = jnp.clip(emax - T.floor_log2(tols) + T.GUARD_BITS, 0,
                    T.TOTAL_PLANES).astype(jnp.int32)
     npl = jnp.where(jnp.all(u_full == 0, axis=-1), 0, npl)
 
@@ -66,7 +65,7 @@ def zfp_encode_blocks_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
         dec = T.dequantize_blocks(T.inv_transform_2d(T.nb2int(u)), emax)
         return jnp.max(jnp.abs(dec - blocks_f), axis=-1)
 
-    for _ in range(MAX_FIX_ITERS):
+    for _ in range(T.MAX_FIX_ITERS):
         bad = block_err(npl) > tols
         npl = jnp.where(bad, jnp.minimum(npl + 2, T.TOTAL_PLANES), npl)
     payload = T.pack_planes(T.truncate_planes(u_full, npl), T.MAX_WORDS)

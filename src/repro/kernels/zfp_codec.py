@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.compression.transform import (
+    GUARD_BITS,
+    MAX_FIX_ITERS,
     MAX_WORDS,
     Q_FIXED_POINT,
     TOTAL_PLANES,
@@ -41,7 +43,6 @@ from repro.compression.transform import (
     pow2_factors,
     scale_by_pow2,
 )
-from repro.compression.zfp import GUARD_BITS, MAX_FIX_ITERS
 
 BLOCK_TILE = 256          # blocks per VMEM tile: 256*16*4B = 16 KiB out tile
 FA_TILE_ROWS = 128        # fixed-accuracy encode: 128-block rows per grid step

@@ -67,8 +67,7 @@ def _resolve_codec(codec, lossy_bits) -> Optional[Codec]:
     if codec is not None and lossy_bits is not None:
         raise ValueError("pass codec= or lossy_bits=, not both")
     if lossy_bits is not None:
-        return get_codec("fixed_rate", bits_per_value=int(lossy_bits),
-                         backend="jnp")
+        return get_codec("fixed_rate", bits_per_value=int(lossy_bits))
     return codec
 
 
@@ -211,13 +210,11 @@ def latest_checkpoint(ckpt_dir: str) -> Optional[str]:
     return None
 
 
-def restore_checkpoint(path: str, template: Dict[str, Any],
-                       backend: Optional[str] = None) -> Tuple[Dict[str, Any], dict]:
+def restore_checkpoint(path: str,
+                       template: Dict[str, Any]) -> Tuple[Dict[str, Any], dict]:
     """Restore into the structure of ``template`` (same pytree defs).
 
-    Lossy checkpoints decode through the codec recorded in the manifest;
-    ``backend`` overrides the decode backend (e.g. restore a jnp-encoded
-    checkpoint through the pallas kernel path).
+    Lossy checkpoints decode through the codec recorded in the manifest.
     """
     with open(os.path.join(path, "manifest.json")) as f:
         meta = json.load(f)
@@ -226,7 +223,7 @@ def restore_checkpoint(path: str, template: Dict[str, Any],
     codec = None
     tree_metas: Dict[str, TreeCodecMeta] = {}
     if codec_meta is not None:
-        codec = codec_from_spec(codec_meta["spec"], backend=backend)
+        codec = codec_from_spec(codec_meta["spec"])
         tree_metas = {name: TreeCodecMeta.from_json(tm)
                       for name, tm in codec_meta["trees"].items()}
     out = {}

@@ -63,15 +63,18 @@ def test_codec_and_lossy_bits_mutually_exclusive(state, tmp_path):
 
 @pytest.mark.parametrize("save_backend", ["jnp", "pallas"])
 def test_save_restore_parity_across_backends(state, tmp_path, save_backend):
-    """Encode on one backend, restore on both: decoded params must match
-    bit-for-bit (the pallas decode falls back to the compiled oracle on
-    CPU, which is asserted bit-identical to the jnp path)."""
-    codec = get_codec("fixed_rate", bits_per_value=13, backend=save_backend)
-    p = ckpt.save_checkpoint(str(tmp_path), 1, state, codec=codec)
-    out_jnp, _ = ckpt.restore_checkpoint(p, state, backend="jnp")
-    out_pal, _ = ckpt.restore_checkpoint(p, state, backend="pallas")
-    assert _max_err(out_jnp, out_pal) == 0.0
-    assert _max_err(out_jnp, state) < 0.02
+    """A checkpoint restores through the codec its manifest records, and
+    the result matches a jnp-encoded checkpoint's bit-for-bit (the pallas
+    path runs the compiled oracle on CPU, held bit-identical to jnp)."""
+    restored = {}
+    for backend in sorted({"jnp", save_backend}):
+        codec = get_codec("fixed_rate", bits_per_value=13, backend=backend)
+        p = ckpt.save_checkpoint(str(tmp_path / backend), 1, state,
+                                 codec=codec)
+        restored[backend], meta = ckpt.restore_checkpoint(p, state)
+        assert meta["codec"]["spec"]["backend"] == backend
+    assert _max_err(restored["jnp"], restored[save_backend]) == 0.0
+    assert _max_err(restored[save_backend], state) < 0.02
 
 
 def test_certified_tolerance_restore_within_bound(state, tmp_path):

@@ -17,7 +17,7 @@ except ImportError:
 from repro.compression import (
     compressed_nbytes, compression_ratio, decode, decode_fixed_rate,
     encode_fixed_accuracy, encode_fixed_accuracy_batch, encode_fixed_rate,
-    blockify, deblockify,
+    blockify, deblockify, get_codec,
 )
 from repro.compression import transform as T
 
@@ -149,13 +149,14 @@ def test_nbytes_accounting(smooth_field):
 @pytest.mark.parametrize("bits", [3, 8, 13])
 @pytest.mark.parametrize("shape", [(3, 2, 10, 7), (2, 6, 16, 8)])
 def test_fixed_rate_batch_pallas_oracle_parity(rng, bits, shape):
-    """use_pallas= must be invisible: payload/emax words bit-identical to
+    """The backend must be invisible: payload/emax words bit-identical to
     the independent pure-jnp encoder, per sample."""
-    from repro.compression import encode_fixed_rate_batch
     xs = jnp.asarray((rng.standard_normal(shape) *
                       10.0 ** rng.integers(-3, 3)).astype(np.float32))
-    pure = encode_fixed_rate_batch(xs, bits)
-    pall = encode_fixed_rate_batch(xs, bits, use_pallas=True)
+    pure = get_codec("fixed_rate", bits_per_value=bits,
+                     backend="jnp").encode_batch(xs)
+    pall = get_codec("fixed_rate", bits_per_value=bits,
+                     backend="pallas").encode_batch(xs)
     assert np.array_equal(np.asarray(pure.payload), np.asarray(pall.payload))
     assert np.array_equal(np.asarray(pure.emax), np.asarray(pall.emax))
     assert np.array_equal(np.asarray(pure.nplanes), np.asarray(pall.nplanes))
@@ -169,9 +170,9 @@ def test_fixed_rate_batch_pallas_oracle_parity(rng, bits, shape):
 
 
 def test_fixed_rate_batch_decodes_like_per_sample(rng):
-    from repro.compression import decode_batch, encode_fixed_rate_batch
+    from repro.compression import decode_batch
     xs = jnp.asarray(rng.standard_normal((4, 2, 9, 6)).astype(np.float32))
-    cf = encode_fixed_rate_batch(xs, 11, use_pallas=True)
+    cf = get_codec("fixed_rate", bits_per_value=11).encode_batch(xs)
     got = np.asarray(decode_batch(cf))
     for j in range(4):
         want = np.asarray(decode_fixed_rate(encode_fixed_rate(xs[j], 11)))
@@ -182,10 +183,10 @@ def test_fixed_rate_batch_decodes_like_per_sample(rng):
 def test_fixed_accuracy_batch_pallas_oracle_parity(rng, shape):
     """backend="pallas" fixed-accuracy encode emits bit-identical streams.
 
-    This is the contract that lets ``CodecPlan.use_pallas`` stay out of the
-    datagen plan hash: flipping the backend cannot change produced bytes.
+    This is the contract that keeps the backend out of the datagen plan
+    hash: the platform that runs the encode cannot change produced bytes.
     """
-    from repro.compression import encode_fixed_accuracy, get_codec
+    from repro.compression import encode_fixed_accuracy
     xs = jnp.asarray(rng.standard_normal(shape).astype(np.float32) * 7.0)
     tols = jnp.asarray(10.0 ** rng.uniform(-4, -1, shape[0]), jnp.float32)
     cf_j = get_codec("fixed_accuracy", backend="jnp").encode_batch(xs, tols)

@@ -207,7 +207,7 @@ def test_fixed_rate_production(tmp_path, ref_fields):
     import jax.numpy as jnp
     plan = ProductionPlan(
         scenarios=(ScenarioPlan("rt", SPEC, num_sims=2, seed=7),),
-        codec=CodecPlan(mode="fixed_rate", bits_per_value=9, use_pallas=True),
+        codec=CodecPlan(mode="fixed_rate", bits_per_value=9),
         shard_size=4)
     rdir = str(tmp_path / "fr")
     assert produce(plan, rdir).finalized
@@ -332,7 +332,6 @@ def test_config_hash_ignores_unused_codec_fields():
     dataset (and so cannot spuriously refuse a resume)."""
     a = dataclasses.replace(PLAN, codec=CodecPlan(tolerance=1e-3))
     b = dataclasses.replace(PLAN, codec=CodecPlan(tolerance=1e-3,
-                                                  use_pallas=True,
                                                   bits_per_value=5))
     assert a.config_hash() == b.config_hash()
     fr = dataclasses.replace(PLAN, codec=CodecPlan(mode="fixed_rate",

@@ -85,8 +85,7 @@ def test_codec_from_plan_roundtrip():
     from repro.datagen import CodecPlan
     fa = codec_from_plan(CodecPlan(mode="fixed_accuracy", tolerance=2e-3))
     assert fa.name == "fixed_accuracy" and fa.tolerance == 2e-3
-    fr = codec_from_plan(CodecPlan(mode="fixed_rate", bits_per_value=9,
-                                   use_pallas=True))
+    fr = codec_from_plan(CodecPlan(mode="fixed_rate", bits_per_value=9))
     assert fr.name == "fixed_rate" and fr.bits_per_value == 9
     assert fr.backend == "pallas"
 

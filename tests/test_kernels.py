@@ -25,7 +25,7 @@ def _blocks_from(rng, n_blocks, kind="smooth"):
 def test_zfp_encode_matches_ref(rng, bits, n_blocks):
     blocks = _blocks_from(rng, n_blocks, "rough")
     p_ref, e_ref = ref.zfp_encode_blocks_ref(blocks, bits)
-    p_k, e_k = ops.zfp_encode_blocks(blocks, bits)
+    p_k, e_k = zfp_codec.zfp_encode_blocks(blocks, bits, interpret=True)
     assert np.array_equal(np.asarray(p_ref), np.asarray(p_k))
     assert np.array_equal(np.asarray(e_ref), np.asarray(e_k))
 
@@ -36,7 +36,7 @@ def test_zfp_decode_matches_ref(rng, bits, n_blocks):
     blocks = _blocks_from(rng, n_blocks, "smooth")
     payload, emax = ref.zfp_encode_blocks_ref(blocks, bits)
     d_ref = ref.zfp_decode_blocks_ref(payload, emax, bits)
-    d_k = ops.zfp_decode_blocks(payload, emax, bits)
+    d_k = zfp_codec.zfp_decode_blocks(payload, emax, bits, interpret=True)
     np.testing.assert_allclose(np.asarray(d_k), np.asarray(d_ref),
                                rtol=0, atol=0)
 
@@ -63,8 +63,9 @@ def _fa_payload(rng, n_blocks, tol):
 def test_zfp_decode_fa_matches_ref(rng, n_blocks, tol):
     cf, expect = _fa_payload(rng, n_blocks, tol)
     d_ref = ref.zfp_decode_blocks_fa_ref(cf.payload, cf.emax, cf.nplanes)
-    d_k = ops.zfp_decode_blocks_fa(cf.payload, cf.emax, cf.nplanes)
-    d_f = ops.zfp_decode_blocks_fa_fast(cf.payload, cf.emax, cf.nplanes)
+    d_k = zfp_codec.zfp_decode_blocks_fa(cf.payload, cf.emax, cf.nplanes,
+                                         interpret=True)
+    d_f = ops.zfp_decode_blocks_fa(cf.payload, cf.emax, cf.nplanes)
     assert np.array_equal(np.asarray(d_k), np.asarray(d_ref))
     assert np.array_equal(np.asarray(d_f), np.asarray(d_ref))
     assert np.array_equal(np.asarray(d_k), np.asarray(expect))
@@ -78,7 +79,8 @@ def test_zfp_decode_fa_zero_plane_blocks(rng):
     x[:4, :] = 0.0                       # first row of 4x4 blocks -> zeros
     cf = encode_fixed_accuracy(jnp.asarray(x), 1e-3)
     assert int(cf.nplanes.min()) == 0 and int(cf.nplanes.max()) > 0
-    out = np.asarray(ops.zfp_decode_blocks_fa(cf.payload, cf.emax, cf.nplanes))
+    out = np.asarray(zfp_codec.zfp_decode_blocks_fa(
+        cf.payload, cf.emax, cf.nplanes, interpret=True))
     zero_rows = np.asarray(cf.nplanes) == 0
     assert np.all(out[zero_rows] == 0.0)
     assert np.array_equal(
@@ -94,8 +96,8 @@ def test_zfp_decode_fa_full_plane_blocks(rng):
     x = (10.0 * rng.standard_normal((8, 8))).astype(np.float32)
     cf = encode_fixed_accuracy(jnp.asarray(x), 1e-12)
     assert int(cf.nplanes.max()) == T.TOTAL_PLANES
-    blocks = np.asarray(ops.zfp_decode_blocks_fa(cf.payload, cf.emax,
-                                                 cf.nplanes))
+    blocks = np.asarray(zfp_codec.zfp_decode_blocks_fa(
+        cf.payload, cf.emax, cf.nplanes, interpret=True))
     expect = np.asarray(T.blockify(T.pad_to_blocks(decode(cf))))
     assert np.array_equal(blocks, expect)
 
@@ -107,7 +109,8 @@ def test_zfp_decode_fa_masks_planes_below_count(rng):
     blocks = _blocks_from(rng, 64, "rough")
     payload, emax = ref.zfp_encode_blocks_ref(blocks, 30)   # full-depth words
     nplanes = jnp.asarray((np.arange(64) % 31).astype(np.int32))
-    got = ops.zfp_decode_blocks_fa(payload, emax, nplanes)
+    got = zfp_codec.zfp_decode_blocks_fa(payload, emax, nplanes,
+                                         interpret=True)
     want = ref.zfp_decode_blocks_fa_ref(payload, emax, nplanes)
     assert np.array_equal(np.asarray(got), np.asarray(want))
     # and the masked result genuinely differs from the unmasked decode
@@ -116,18 +119,22 @@ def test_zfp_decode_fa_masks_planes_below_count(rng):
 
 
 def test_zfp_fast_path_identical(rng):
-    """The compiled-oracle throughput path must equal the kernel path."""
+    """Off the TPU the ops entries run the compiled oracle: it must equal
+    the fixed-rate kernels run in interpret mode."""
     blocks = _blocks_from(rng, 64, "rough")
-    payload, emax = ops.zfp_encode_blocks(blocks, 12)
-    a = ops.zfp_decode_blocks(payload, emax, 12)
-    b = ops.zfp_decode_blocks_fast(payload, emax, 12)
+    payload, emax = zfp_codec.zfp_encode_blocks(blocks, 12, interpret=True)
+    for k, o in zip((payload, emax), ops.zfp_encode_blocks(blocks, 12)):
+        assert np.array_equal(np.asarray(k), np.asarray(o))
+    a = zfp_codec.zfp_decode_blocks(payload, emax, 12, interpret=True)
+    b = ops.zfp_decode_blocks(payload, emax, 12)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0, atol=0)
 
 
 def test_encode_decode_field_roundtrip(rng):
+    from repro.compression import get_codec
     x = jnp.asarray(rng.standard_normal((6, 33, 18)).astype(np.float32))
-    cf = ops.encode_field(x, 20)
-    out = ops.decode_field(cf)
+    codec = get_codec("fixed_rate", bits_per_value=20)
+    out = codec.decode_batch(codec.encode_batch(x[None]))[0]
     assert out.shape == x.shape
     assert float(jnp.max(jnp.abs(out - x))) < 1e-3
 
@@ -141,7 +148,7 @@ FA_TILE = zfp_codec.FA_TILE_ROWS * 128          # blocks per grid step
 
 def _assert_fa_encode_matches_ref(blocks, tols):
     want = ref.zfp_encode_blocks_fa_ref(blocks, tols)
-    got = ops.zfp_encode_blocks_fa(blocks, tols)
+    got = zfp_codec.zfp_encode_blocks_fa(blocks.T, tols, interpret=True)
     for name, w, g in zip(("payload", "emax", "nplanes"), want, got):
         assert np.array_equal(np.asarray(g), np.asarray(w)), name
     return want
@@ -185,7 +192,7 @@ def _fa_special_case(rng, case):
                                   "pow2_tolerance", "subnormal_and_large",
                                   "tolerances_change_in_tile"])
 def test_zfp_encode_fa_edge_cases_bit_identical(rng, case):
-    from repro.compression.zfp import GUARD_BITS, MAX_FIX_ITERS
+    from repro.compression.transform import GUARD_BITS, MAX_FIX_ITERS
     blocks, tols = _fa_special_case(rng, case)
     _, emax, npl = _assert_fa_encode_matches_ref(blocks, tols)
     if case == "all_fix_steps":
@@ -204,14 +211,15 @@ def test_zfp_encode_fa_edge_cases_bit_identical(rng, case):
                          ids=["pchip", "rt", "ragged"])
 def test_encode_fixed_accuracy_batch_pallas_matches_jnp(rng, shape):
     """Small stand-ins of the PCHIP (square) and RT (3:1) stacks, and one
-    whose H and W are not multiples of 4: ``use_pallas=True`` equals the
-    jnp encoder, and so does the kernel on the same coefficient-major stack."""
-    from repro.compression import encode_fixed_accuracy_batch
+    whose H and W are not multiples of 4: the codec's kernel backend equals
+    the jnp encoder, and so does the kernel on the same coefficient-major
+    stack."""
+    from repro.compression import get_codec
     xs = jnp.asarray(rng.standard_normal(shape).astype(np.float32)
                      * 10.0 ** rng.uniform(-2, 2, shape[:2] + (1, 1)))
     tols = jnp.asarray(10.0 ** rng.uniform(-4, -1, shape[0]), jnp.float32)
-    want = encode_fixed_accuracy_batch(xs, tols)
-    got = encode_fixed_accuracy_batch(xs, tols, use_pallas=True)
+    want = get_codec("fixed_accuracy", backend="jnp").encode_batch(xs, tols)
+    got = get_codec("fixed_accuracy", backend="pallas").encode_batch(xs, tols)
     assert (got.shape, got.padded_shape) == (want.shape, want.padded_shape)
     n, nb = want.emax.shape
     coefs = T.blockify_coef_major(T.pad_to_blocks(xs))
@@ -273,7 +281,8 @@ def test_zfp_encode_fa_matches_ref(rng, n_blocks, tol):
     blocks = _blocks_from(rng, n_blocks, "rough")
     tols = jnp.full((n_blocks,), tol, jnp.float32)
     p_ref, e_ref, n_ref = ref.zfp_encode_blocks_fa_ref(blocks, tols)
-    p_k, e_k, n_k = ops.zfp_encode_blocks_fa(blocks, tols)
+    p_k, e_k, n_k = zfp_codec.zfp_encode_blocks_fa(blocks.T, tols,
+                                                   interpret=True)
     assert np.array_equal(np.asarray(p_k), np.asarray(p_ref))
     assert np.array_equal(np.asarray(e_k), np.asarray(e_ref))
     assert np.array_equal(np.asarray(n_k), np.asarray(n_ref))
@@ -285,7 +294,8 @@ def test_zfp_encode_fa_mixed_tolerances(rng):
     blocks = _blocks_from(rng, 192, "rough")
     tols = jnp.asarray(10.0 ** rng.uniform(-5, 0, 192), jnp.float32)
     p_ref, e_ref, n_ref = ref.zfp_encode_blocks_fa_ref(blocks, tols)
-    p_k, e_k, n_k = ops.zfp_encode_blocks_fa(blocks, tols)
+    p_k, e_k, n_k = zfp_codec.zfp_encode_blocks_fa(blocks.T, tols,
+                                                   interpret=True)
     assert np.array_equal(np.asarray(p_k), np.asarray(p_ref))
     assert np.array_equal(np.asarray(e_k), np.asarray(e_ref))
     assert np.array_equal(np.asarray(n_k), np.asarray(n_ref))
@@ -295,7 +305,8 @@ def test_zfp_encode_fa_zero_blocks(rng):
     """All-zero (and sub-flush-threshold) blocks keep zero planes."""
     blocks = jnp.zeros((40, 16), jnp.float32)
     blocks = blocks.at[7].set(1e-40)            # below the 2^-120 flush
-    p, e, n = ops.zfp_encode_blocks_fa(blocks, jnp.full((40,), 1e-3))
+    p, e, n = zfp_codec.zfp_encode_blocks_fa(blocks.T, jnp.full((40,), 1e-3),
+                                             interpret=True)
     assert not np.asarray(p).any()
     assert not np.asarray(e).any()
     assert not np.asarray(n).any()
@@ -305,15 +316,18 @@ def test_zfp_encode_fa_zero_blocks(rng):
 def test_zfp_encode_fa_roundtrip_honors_bound(rng, tol):
     """Kernel encode -> kernel decode stays within the L-inf tolerance."""
     blocks = _blocks_from(rng, 128, "smooth")
-    p, e, n = ops.zfp_encode_blocks_fa(blocks, jnp.full((128,), tol))
-    dec = ops.zfp_decode_blocks_fa(p, e, n)
+    p, e, n = zfp_codec.zfp_encode_blocks_fa(
+        blocks.T, jnp.full((128,), tol), interpret=True)
+    dec = zfp_codec.zfp_decode_blocks_fa(p, e, n, interpret=True)
     assert float(jnp.max(jnp.abs(dec - blocks))) <= tol
 
 
 def test_zfp_encode_fa_fast_path_identical(rng):
-    """The compiled-oracle throughput path is bit-identical to the kernel."""
+    """Off the TPU the ops entry runs the compiled oracle: it is
+    bit-identical to the kernel run in interpret mode."""
     blocks = _blocks_from(rng, 96, "rough")
     tols = jnp.asarray(10.0 ** rng.uniform(-4, -1, 96), jnp.float32)
-    for a, b in zip(ops.zfp_encode_blocks_fa(blocks, tols),
-                    ops.zfp_encode_blocks_fa_fast(blocks, tols)):
+    for a, b in zip(zfp_codec.zfp_encode_blocks_fa(blocks.T, tols,
+                                                   interpret=True),
+                    ops.zfp_encode_blocks_fa(blocks.T, tols)):
         assert np.array_equal(np.asarray(a), np.asarray(b))

@@ -90,11 +90,10 @@ def test_certify_chunk_encode_is_lane_dense(one_chip):
     custom call under the name ``bench/metrics/encode_roofline.py`` reads,
     and no operand padded out to 128 lanes: the block-major (nb, 16) layout
     took 4 GiB of temporaries here."""
-    from repro.compression import encode_fixed_accuracy_batch
-    compiled = encode_fixed_accuracy_batch.lower(
+    from repro.compression import api
+    compiled = api._encode_fa_kernel.lower(
         _spec(CERTIFY_CHUNK, jnp.float32, one_chip),
-        _spec(CERTIFY_CHUNK[:1], jnp.float32, one_chip),
-        use_pallas=True).compile()
+        _spec(CERTIFY_CHUNK[:1], jnp.float32, one_chip)).compile()
     assert re.search(r"%zfp_encode_blocks_fa\.\d+ = ", compiled.as_text())
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 

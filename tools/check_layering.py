@@ -15,7 +15,8 @@ Two rules, enforced over every module in ``src/repro`` by AST inspection
    nothing from ``repro`` at all (only the stdlib and ``jax``).
 
    Function-local (lazy) imports are the sanctioned escape hatch for the
-   few documented back-edges -- compression -> kernels (backend dispatch),
+   few documented back-edges -- compression/api.py -> kernels.ops (the
+   codec's kernel backend; no other compression module imports kernels),
    distributed.sharding -> train.optimizer (AdamState re-export),
    core.ensemble / train.checkpoint cross-links -- because they defer the
    dependency to call time and cannot create import cycles.  In particular
