@@ -10,7 +10,6 @@ Public API:
 from repro.compression.transform import Q_FIXED_POINT, TOTAL_PLANES
 from repro.compression.zfp import (
     CompressedField,
-    FAEncodeState,
     compressed_nbytes,
     compressed_nbytes_batch,
     compression_ratio,
@@ -21,15 +20,13 @@ from repro.compression.zfp import (
     encode_fixed_accuracy_batch,
     encode_fixed_rate,
     encode_fixed_rate_batch,
-    fa_plane_counts,
-    fa_precompute_batch,
-    fa_stats_batch,
     trim_to_nplanes,
 )
 from repro.compression.transform import blockify, deblockify
 from repro.compression.api import (
     BACKENDS,
     Codec,
+    FAEncodeState,
     FixedAccuracyCodec,
     FixedRateCodec,
     LeafSpec,
@@ -43,6 +40,8 @@ from repro.compression.api import (
     decode_stacked_payloads,
     decode_tree,
     encode_tree,
+    fa_precompute_batch,
+    fa_stats_batch,
     get_codec,
     leaf_2d_shape,
     register_codec,
@@ -82,7 +81,6 @@ __all__ = [
     "encode_fixed_rate",
     "encode_fixed_rate_batch",
     "encode_tree",
-    "fa_plane_counts",
     "fa_precompute_batch",
     "fa_stats_batch",
     "get_codec",

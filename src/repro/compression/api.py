@@ -39,8 +39,9 @@ import numpy as np
 
 from repro.compression import transform as T
 from repro.compression.zfp import (
-    CompressedField, compressed_nbytes_batch, decode_batch as _decode_batch_jnp,
-    encode_fixed_accuracy_batch, encode_fixed_rate_batch, trim_to_nplanes,
+    CompressedField, _header_bytes_per_block, compressed_nbytes_batch,
+    decode_batch as _decode_batch_jnp, encode_fixed_accuracy_batch,
+    encode_fixed_rate_batch, trim_to_nplanes,
 )
 from repro.obs import trace as obs_trace
 
@@ -140,6 +141,95 @@ def _encode_fr_kernel(xs: jnp.ndarray, bits_per_value: int) -> CompressedField:
     nplanes = jnp.full((n, nb), bits_per_value, dtype=jnp.int32)
     return CompressedField(payload.reshape(n, nb, -1), emax.reshape(n, nb),
                            nplanes, xs.shape[1:], xp.shape[1:])
+
+
+# ---------------------------------------------------------------------------
+# Algorithm 1's stats pass (core/tolerance.py's search body)
+# ---------------------------------------------------------------------------
+# The tolerance search evaluates many tolerances against the SAME sample
+# stack and needs, per tolerance, only each sample's L1 error and logical
+# bytes.  ``fa_precompute_batch`` lays the padded stack out once, as the
+# coefficient-major (16, R, 128) slabs of the fixed-accuracy kernels with a
+# per-block valid-pixel mask beside it; each search iteration is then one
+# ``ops.zfp_search_stats_fa`` pass over the slabs (quantize, lift, plane
+# guess and correction as the encode kernel runs them, then the truncated
+# decode reduced to a per-block L1 sum) and two small per-sample sums.  No
+# payload is packed: packing waits for the accepted tolerance.  The plane
+# counts are the encode's by construction, and the L1 is the roundtrip's up
+# to the order of its sum.
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class FAEncodeState:
+    """A (N, ...) sample stack laid out for the search's stats passes.
+
+    slabs : (16, R, 128) float32 coefficient-major padded blocks
+    valid : (R, 128) int32 per block, bit 4r + c set where pixel (r, c)
+            lies in the field, not in its edge padding; 0 past the stack
+    n, nb : samples, and blocks per sample
+    sample_size : values per (unpadded) sample
+    """
+    slabs: jnp.ndarray
+    valid: jnp.ndarray
+    n: int
+    nb: int
+    sample_size: int
+
+    def tree_flatten(self):
+        return ((self.slabs, self.valid),
+                (self.n, self.nb, self.sample_size))
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+
+def _valid_bits(rows: int, n_blocks: int, h: int, w: int) -> jnp.ndarray:
+    """(rows, 128) valid-pixel bits of the blocks of an (..., h, w) stack
+    padded to multiples of 4, in ``blockify`` order."""
+    hb, wb = -(-h // 4), -(-w // 4)
+    b = (jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 0) * 128
+         + jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1))
+    p = b % (hb * wb)
+    n_rows = jnp.clip(h - 4 * (p // wb), 0, 4)
+    row_bits = (1 << jnp.clip(w - 4 * (p % wb), 0, 4)) - 1
+    bits = sum(jnp.where(r < n_rows, row_bits << 4 * r, 0) for r in range(4))
+    return jnp.where(b < n_blocks, bits, 0)
+
+
+@jax.jit
+def fa_precompute_batch(xs: jnp.ndarray) -> FAEncodeState:
+    """Lay a (N, ...) stack out once for the stats passes of a search."""
+    from repro.kernels import ops        # lazy: kernels rank above compression
+    n = xs.shape[0]
+    xp = T.pad_to_blocks(xs.astype(jnp.float32))
+    coefs = T.blockify_coef_major(xp)                # (16, N * nb)
+    rows, _ = ops.fa_slab_rows(coefs.shape[1])
+    slabs = jnp.pad(coefs, ((0, 0), (0, rows * 128 - coefs.shape[1])))
+    valid = _valid_bits(rows, coefs.shape[1], *xs.shape[-2:])
+    return FAEncodeState(slabs.reshape(16, rows, 128), valid, n,
+                         coefs.shape[1] // n, int(np.prod(xs.shape[1:])))
+
+
+def fa_stats_batch(state: FAEncodeState, tols: jnp.ndarray):
+    """Stats-only roundtrip: per-sample ``(l1, nbytes)`` at tolerances ``tols``.
+
+    ``nbytes`` is the fixed-accuracy encode's at ``tols``, exactly; ``l1``
+    is ``mean |decode(encode(xs, tols)) - xs|`` summed per block first.
+    """
+    from repro.kernels import ops        # lazy: kernels rank above compression
+    n, nb = state.n, state.nb
+    rows = state.valid.shape[0]
+    tol = jnp.pad(jnp.repeat(jnp.asarray(tols, jnp.float32), nb),
+                  (0, rows * 128 - n * nb), constant_values=1.0)
+    npl, l1 = ops.zfp_search_stats_fa(state.slabs, tol.reshape(rows, 128),
+                                      state.valid)
+    npl = npl.reshape(-1)[:n * nb].reshape(n, nb)
+    l1 = jnp.sum(l1.reshape(-1)[:n * nb].reshape(n, nb), axis=1)
+    nbytes = (_header_bytes_per_block("fixed_accuracy") * nb
+              + 2 * jnp.sum(npl, axis=1))
+    return l1 / state.sample_size, nbytes
 
 
 def _pad4(shape2d) -> Tuple[int, ...]:

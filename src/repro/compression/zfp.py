@@ -254,104 +254,3 @@ def _crop(xp: jnp.ndarray, shape) -> jnp.ndarray:
         return xp
     slices = tuple(slice(0, s) for s in shape)
     return xp[slices]
-
-
-# ---------------------------------------------------------------------------
-# stats-only fixed-accuracy roundtrip (Algorithm 1's inner loop)
-# ---------------------------------------------------------------------------
-# The tolerance search (core/tolerance.py) evaluates many tolerances against
-# the SAME sample stack.  Everything tolerance-independent — quantize,
-# forward lift, negabinary — is hoisted into FAEncodeState once; each search
-# iteration then only (a) re-runs the plane-count guess + correction loop
-# and (b) reduces the truncated-coefficient decode to per-sample L1 and
-# logical nbytes.  No pack_planes/unpack_planes ever runs: the search body
-# needs statistics, not a payload, so packing waits for the final accepted
-# tolerance.  The numbers are bit-identical to the packed roundtrip
-# (pack/unpack at full word width is exact), asserted in tests.
-
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class FAEncodeState:
-    """Tolerance-independent encode state for a (N, ...) sample stack.
-
-    xs     : (N, ...) float32 original samples (uncropped, unpadded)
-    blocks : (N*nb, 16) float32 padded block values
-    u_full : (N*nb, 16) int32 full-precision negabinary coefficients
-    emax   : (N*nb,)   int32 per-block shared exponents
-    """
-    xs: jnp.ndarray
-    blocks: jnp.ndarray
-    u_full: jnp.ndarray
-    emax: jnp.ndarray
-    padded_shape: Tuple[int, ...]
-
-    def tree_flatten(self):
-        return ((self.xs, self.blocks, self.u_full, self.emax),
-                (self.padded_shape,))
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children, aux[0])
-
-
-@jax.jit
-def fa_precompute_batch(xs: jnp.ndarray) -> FAEncodeState:
-    """Run the tolerance-independent half of the fixed-accuracy encode."""
-    xs = xs.astype(jnp.float32)
-    xp = T.pad_to_blocks(xs)
-    blocks = T.blockify(xp)                          # (N * nb, 16)
-    u_full, emax = _encode_blocks(blocks)
-    return FAEncodeState(xs, blocks, u_full, emax, xp.shape[1:])
-
-
-def fa_plane_counts(state: FAEncodeState, tols: jnp.ndarray) -> jnp.ndarray:
-    """(N,) tolerances -> (N, nb) per-block plane counts.
-
-    Identical guess + bound-verification correction as
-    :func:`encode_fixed_accuracy` (same arithmetic per block; running the
-    flattened batch under one while_loop instead of per-sample loops cannot
-    change the fixpoint — the correction body is a no-op on settled blocks).
-    """
-    n = state.xs.shape[0]
-    nb = state.emax.shape[0] // n
-    tols_b = jnp.repeat(jnp.asarray(tols, jnp.float32), nb)
-    npl = _planes_for_tolerance(state.emax, tols_b)
-    npl = jnp.where(jnp.all(state.u_full == 0, axis=-1), 0, npl)
-
-    def block_err(npl):
-        u = T.truncate_planes(state.u_full, npl)
-        dec = _decode_blocks(u, state.emax)
-        return jnp.max(jnp.abs(dec - state.blocks), axis=-1)
-
-    def cond(s):
-        npl, it = s
-        bad = (block_err(npl) > tols_b) & (npl < T.TOTAL_PLANES)
-        return jnp.any(bad) & (it < T.MAX_FIX_ITERS)
-
-    def body(s):
-        npl, it = s
-        bad = block_err(npl) > tols_b
-        return jnp.where(bad, jnp.minimum(npl + 2, T.TOTAL_PLANES), npl), it + 1
-
-    npl, _ = jax.lax.while_loop(cond, body, (npl, jnp.int32(0)))
-    return npl.reshape(n, nb)
-
-
-def fa_stats_batch(state: FAEncodeState, tols: jnp.ndarray):
-    """Stats-only roundtrip: per-sample ``(l1, nbytes)`` at tolerances ``tols``.
-
-    Equals ``(mean |decode(encode(xs, tols)) - xs|, nbytes(encode(...)))``
-    bit-for-bit, with no plane packing/unpacking and no re-quantize/lift.
-    """
-    n = state.xs.shape[0]
-    npl = fa_plane_counts(state, tols)               # (N, nb)
-    u = T.truncate_planes(state.u_full, npl.reshape(-1))
-    dec = _decode_blocks(u, state.emax)
-    xd = T.deblockify(dec, (n,) + tuple(state.padded_shape))
-    xd = _crop(xd, state.xs.shape)
-    axes = tuple(range(1, state.xs.ndim))
-    l1 = jnp.mean(jnp.abs(xd - state.xs), axis=axes)
-    nbytes = (_header_bytes_per_block("fixed_accuracy") * npl.shape[1]
-              + 2 * jnp.sum(npl, axis=-1))
-    return l1, nbytes

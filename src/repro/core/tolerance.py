@@ -14,6 +14,16 @@ Two entry points:
                            per-sample active masks: building tolerances for
                            N samples is a single compiled dispatch, not
                            N x iters encode calls.
+
+The batched search lays the stack out once, coefficient-major: the 16
+coefficients of every 4x4 block as (16, R, 128) slabs, one block per
+(row, lane), as the fixed-accuracy encode kernel reads them
+(``fa_precompute_batch``).  Each iteration is then one stats pass of the
+codec's kernel over those slabs (``fa_stats_batch``: on a TPU one Pallas
+call, ``zfp_search_stats_fa``; elsewhere its jnp oracle), which returns
+every block's plane count and L1 error sum, and nothing inside the loop
+touches a block-major (nb, 16) array.  ``find_tolerance_batch`` adds the
+passes a search took to the ``tolerance.stats_passes`` counter.
 """
 from __future__ import annotations
 
@@ -26,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.compression import fa_precompute_batch, fa_stats_batch, get_codec
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 C_D = {1: 1.044, 2: 1.089, 3: 1.134, 4: 1.178}   # Fox & Lindstrom, Appendix A
@@ -128,13 +139,15 @@ def _search_batch(xs: jnp.ndarray, es: jnp.ndarray, d: int, max_iters: int):
     one batched encode/decode; finished samples are masked out of the state
     updates, so results match find_tolerance exactly.
 
-    The loop body is stats-only: quantize / forward lift / negabinary are
-    hoisted out of the while_loop once (``fa_precompute_batch``), and each
-    iteration only re-derives per-block plane counts and the truncated
-    decode (``fa_stats_batch``) — the loop needs nothing but per-sample L1
+    The loop body is stats-only: the coefficient-major layout is built
+    once, before the while_loop (``fa_precompute_batch``), and each
+    iteration is one stats pass over it (``fa_stats_batch``): the plane
+    counts the encode would keep and the truncated decode's L1, summed per
+    block and then per sample.  The loop needs nothing but per-sample L1
     and byte counts, and pack(MAX_WORDS)→unpack is an exact inverse, so the
     decisions are those of find_tolerance's full encode→decode roundtrip
-    (tests assert so).
+    (tests assert so); only the order of the L1 sum differs, which can move
+    an ``l1 <= e`` decision only where L1 lies within a few ulps of ``e``.
     """
     n = xs.shape[0]
     sample_size = int(np.prod(xs.shape[1:]))
@@ -208,7 +221,9 @@ def find_tolerance_batch(samples: np.ndarray | Sequence[np.ndarray],
     Equivalent to ``[find_tolerance(s, e) for s, e in zip(...)]`` but the
     whole search runs device-side: one jitted lax.while_loop whose
     stats-only body evaluates every still-active sample (see
-    ``_search_batch``).
+    ``_search_batch``).  Each iteration is one stats pass over the whole
+    stack; the passes taken, ``max(iterations)``, are added to the
+    ``tolerance.stats_passes`` counter.
     """
     xs = jnp.asarray(np.stack([np.asarray(s, np.float32) for s in samples])
                      if not isinstance(samples, (np.ndarray, jnp.ndarray))
@@ -221,5 +236,7 @@ def find_tolerance_batch(samples: np.ndarray | Sequence[np.ndarray],
         # the host waits here for the whole search to finish on the device
         with obs_trace.span("tolerance.readback", cat="certify"):
             tol, l1, ratio, iters = (np.asarray(a) for a in found)
-        sp.set(max_iterations=int(iters.max(initial=0)))
+        passes = int(iters.max(initial=0))
+        sp.set(max_iterations=passes)
+    obs_metrics.get_registry().counter("tolerance.stats_passes").add(passes)
     return BatchToleranceResult(tol, np.asarray(es), l1, ratio, iters)

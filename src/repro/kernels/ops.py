@@ -77,6 +77,25 @@ def _ref_encode_fa_jit(coefs, tols):
     return ref.zfp_encode_blocks_fa_ref(coefs.T, tols)
 
 
+def zfp_search_stats_fa(slabs, tols, valid):
+    """Algorithm 1's stats pass on coefficient-major (16, R, 128) slabs at
+    (R, 128) per-block tolerances and valid-pixel bits -> ((R, 128) int32
+    plane counts, (R, 128) f32 per-block L1 sums)."""
+    return _kernel_on_tpu(zfp_codec.zfp_search_stats_fa, _ref_search_stats_jit,
+                          slabs, tols, valid)
+
+
+@jax.jit
+def _ref_search_stats_jit(slabs, tols, valid):
+    npl, l1 = ref.zfp_search_stats_fa_ref(
+        slabs.reshape(16, -1).T, tols.reshape(-1), valid.reshape(-1))
+    return npl.reshape(tols.shape), l1.reshape(tols.shape)
+
+
+# rows of 128 blocks that the coefficient-major kernels take for nb blocks
+fa_slab_rows = zfp_codec.fa_slab_rows
+
+
 def flash_attention(q, k, v, *, causal=True, sm_scale=None, window=None):
     kernel = partial(_fa.flash_attention, causal=causal, sm_scale=sm_scale,
                      window=window)

@@ -9,6 +9,7 @@ different code.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.compression import transform as T
@@ -40,17 +41,20 @@ def zfp_decode_blocks_ref(payload: jnp.ndarray, emax: jnp.ndarray,
     return T.dequantize_blocks(qi, emax)
 
 
-def zfp_encode_blocks_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
-    """Fixed-accuracy encode oracle with per-block L-inf tolerances.
+def _fa_decode_ref(u_full, emax, npl):
+    """(nb, 16) f32 blocks decoded from ``u_full`` kept to ``npl`` planes."""
+    u = T.truncate_planes(u_full, npl)
+    return T.dequantize_blocks(T.inv_transform_2d(T.nb2int(u)), emax)
 
-    (nb, 16) f32 blocks, (nb,) f32 tols -> ((nb, MAX_WORDS) int32 payload,
-    (nb,) int32 emax, (nb,) int32 nplanes).  Mirrors
-    ``compression/zfp.py::encode_fixed_accuracy`` block-for-block: plane
-    guess from ``emax - floor(log2(tol)) + GUARD_BITS``, zero-block
-    short-circuit, then the bound-verification correction run a static
-    ``MAX_FIX_ITERS`` times (the while_loop's body is a no-op once a block's
-    realized error is within tolerance, so the unroll reaches the identical
-    fixpoint).
+
+def _fa_planes_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
+    """Fixed-accuracy plane counts of (nb, 16) blocks at (nb,) tolerances.
+
+    Mirrors ``compression/zfp.py::encode_fixed_accuracy`` block-for-block:
+    plane guess from ``emax - floor(log2(tol)) + GUARD_BITS``, zero-block
+    short-circuit, then the bound-verification correction, at most
+    ``MAX_FIX_ITERS`` steps of +2 planes to the blocks whose realized error
+    misses their tolerance.  Returns ``(u_full, emax, npl)``.
     """
     emax = T.block_emax(blocks_f)
     qi = T.quantize_blocks(blocks_f, emax)
@@ -60,16 +64,55 @@ def zfp_encode_blocks_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
                    T.TOTAL_PLANES).astype(jnp.int32)
     npl = jnp.where(jnp.all(u_full == 0, axis=-1), 0, npl)
 
-    def block_err(npl):
-        u = T.truncate_planes(u_full, npl)
-        dec = T.dequantize_blocks(T.inv_transform_2d(T.nb2int(u)), emax)
-        return jnp.max(jnp.abs(dec - blocks_f), axis=-1)
+    def bad(npl):
+        dec = _fa_decode_ref(u_full, emax, npl)
+        return jnp.max(jnp.abs(dec - blocks_f), axis=-1) > tols
 
-    for _ in range(T.MAX_FIX_ITERS):
-        bad = block_err(npl) > tols
-        npl = jnp.where(bad, jnp.minimum(npl + 2, T.TOTAL_PLANES), npl)
+    def cond(s):
+        npl, it = s
+        return jnp.any(bad(npl) & (npl < T.TOTAL_PLANES)) & (
+            it < T.MAX_FIX_ITERS)
+
+    def body(s):
+        npl, it = s
+        return jnp.where(bad(npl), jnp.minimum(npl + 2, T.TOTAL_PLANES),
+                         npl), it + 1
+
+    npl, _ = jax.lax.while_loop(cond, body, (npl, jnp.int32(0)))
+    return u_full, emax, npl
+
+
+def zfp_encode_blocks_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray):
+    """Fixed-accuracy encode oracle with per-block L-inf tolerances.
+
+    (nb, 16) f32 blocks, (nb,) f32 tols -> ((nb, MAX_WORDS) int32 payload,
+    (nb,) int32 emax, (nb,) int32 nplanes), planes from ``_fa_planes_ref``.
+    The kernel runs the correction a static ``MAX_FIX_ITERS`` times; the
+    step is a no-op once a block's realized error is within tolerance, so
+    both reach the identical fixpoint.
+    """
+    u_full, emax, npl = _fa_planes_ref(blocks_f, tols)
     payload = T.pack_planes(T.truncate_planes(u_full, npl), T.MAX_WORDS)
     return payload, emax, npl
+
+
+def zfp_search_stats_fa_ref(blocks_f: jnp.ndarray, tols: jnp.ndarray,
+                            valid: jnp.ndarray):
+    """Algorithm 1's stats-pass oracle.
+
+    (nb, 16) f32 blocks, (nb,) f32 tols, (nb,) int32 valid-pixel bits ->
+    ((nb,) int32 plane counts, (nb,) f32 ``sum |decode - x|`` over the
+    pixels k whose bit k is set), the sum taken in coefficient order 0..15
+    as the kernel takes it.
+    """
+    u_full, emax, npl = _fa_planes_ref(blocks_f, tols)
+    err = jnp.abs(_fa_decode_ref(u_full, emax, npl) - blocks_f)
+    on = ((valid.astype(jnp.int32)[:, None] >> jnp.arange(16)) & 1) != 0
+    err = jnp.where(on, err, 0.0)
+    l1 = err[:, 0]
+    for k in range(1, 16):
+        l1 = l1 + err[:, k]
+    return npl, l1
 
 
 def zfp_decode_blocks_fa_ref(payload: jnp.ndarray, emax: jnp.ndarray,

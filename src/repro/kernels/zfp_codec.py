@@ -11,12 +11,17 @@ kernels lay the blocks out in one of two ways:
     16 of a vreg's 128 lanes do work, the transform's other direction needs
     lane shuffles, and the (nb, 16) / (nb, 1) operands are padded to 128
     lanes in HBM.
-  * coefficient-major (fixed-accuracy encode): 16 slabs, one per
-    coefficient k = 4r + c, each (R, 128) with one block per (row, lane);
-    the grid tiles R at FA_TILE_ROWS.  Every step is elementwise across
-    slabs, the 4x4 transposes are a re-indexing of the slab list.
-      (16, TR, 128) f32 + tol (TR, 128) f32 -> payload (MAX_WORDS, TR, 128)
-      int32 + emax (TR, 128) int32 + nplanes (TR, 128) int32
+  * coefficient-major (fixed-accuracy encode, Algorithm 1's stats pass):
+    16 slabs, one per coefficient k = 4r + c, each (R, 128) with one block
+    per (row, lane); the grid tiles R at FA_TILE_ROWS (``fa_slab_rows``).
+    Every step is elementwise across slabs, the 4x4 transposes are a
+    re-indexing of the slab list, and both kernels take their plane counts
+    from one helper (``_fa_planes``).
+      encode: (16, TR, 128) f32 + tol (TR, 128) f32 -> payload
+              (MAX_WORDS, TR, 128) int32 + emax, nplanes (TR, 128) int32
+      stats:  (16, TR, 128) f32 + tol (TR, 128) f32 + valid-pixel bits
+              (TR, 128) int32 -> nplanes (TR, 128) int32 + L1 sum
+              (TR, 128) f32
 
 All arithmetic is bitwise/elementwise on int32 lanes plus small static
 loops: pure VPU work.
@@ -293,50 +298,99 @@ def _inv_transform_slabs(s):
     return s
 
 
+def fa_slab_rows(nb: int):
+    """(rows, tile) of the coefficient-major kernels for ``nb`` blocks.
+
+    The blocks lie along the 128 lanes, ``rows`` rows of them: enough for
+    ``nb``, rounded up to whole grid tiles of ``tile`` rows (``FA_TILE_ROWS``,
+    or fewer, a multiple of ``FA_SUB_ROWS``, for a short stack).
+    """
+    rows = -(-nb // 128)
+    tile = min(FA_TILE_ROWS, -(-rows // FA_SUB_ROWS) * FA_SUB_ROWS)
+    return -(-rows // tile) * tile, tile
+
+
+def _fa_planes(x, tol, valid=None):
+    """Fixed-accuracy plane counts of one sub-tile, coefficient-major.
+
+    ``x`` is the 16 (FA_SUB_ROWS, 128) f32 slabs (slab k holds coefficient
+    k = 4r + c of one block per (row, lane)), ``tol`` the blocks' L-inf
+    tolerances.  Every step is elementwise across the slabs: quantize,
+    forward lift, negabinary, the plane guess (``emax - floor(log2(tol)) +
+    GUARD_BITS``, the floor read from the exponent field), zero blocks to 0
+    planes, then the bound-verification correction: two more planes for
+    every block whose decode misses its tolerance, until a step grows no
+    block of the sub-tile or after ``MAX_FIX_ITERS`` steps.  The jnp
+    encoder's while_loop runs the same step at most that many times, and
+    after a step that grows nothing every later step is a no-op, so stopping
+    there is bit-exact.
+
+    Returns ``(emax, u_full, npl, l1)``: the blocks' exponents, their
+    full-precision negabinary slabs and their plane counts.  Given
+    ``valid``, the blocks' valid-pixel bits (bit k for coefficient k), ``l1``
+    is each block's ``sum |decode - x|`` at those plane counts over its
+    valid pixels, added in coefficient order 0..15: the correction's last
+    decode where that step grew nothing, one more decode where it stopped
+    at ``MAX_FIX_ITERS``.  Without ``valid`` it is None.
+    """
+    neg = jnp.int32(_NEG)
+    emax = _emax_slab(functools.reduce(jnp.maximum, map(jnp.abs, x)))
+    f1, f2 = pow2_factors(Q_FIXED_POINT - emax)
+    qi = [jnp.round((v * f1) * f2).astype(jnp.int32) for v in x]
+    u_full = [(c + neg) ^ neg for c in _fwd_transform_slabs(qi)]
+
+    npl = jnp.clip(emax - floor_log2(tol) + GUARD_BITS, 0, TOTAL_PLANES)
+    npl = jnp.where(functools.reduce(jnp.bitwise_or, u_full) == 0, 0, npl)
+    g1, g2 = pow2_factors(emax - Q_FIXED_POINT)
+
+    def errors(npl):
+        """|decode - x| of each coefficient at ``npl`` planes."""
+        keep = jnp.int32(-1) << jnp.clip(TOTAL_PLANES - npl, 0, 31)
+        deci = _inv_transform_slabs([((u & keep) ^ neg) - neg for u in u_full])
+        return [jnp.abs((d.astype(jnp.float32) * g1) * g2 - v)
+                for d, v in zip(deci, x)]
+
+    def l1_of(err):
+        return functools.reduce(jnp.add, [
+            jnp.where(((valid >> k) & 1) != 0, e, 0.0)
+            for k, e in enumerate(err)])
+
+    def more(state):
+        it, _, grew, _ = state
+        return (it < MAX_FIX_ITERS) & grew
+
+    def fix(state):
+        it, npl, _, l1 = state
+        err = errors(npl)
+        grow = ((functools.reduce(jnp.maximum, err) > tol)
+                & (npl < TOTAL_PLANES))
+        if valid is not None:
+            l1 = l1_of(err)
+        return (it + 1, jnp.where(grow, jnp.minimum(npl + 2, TOTAL_PLANES),
+                                  npl),
+                jnp.max(grow.astype(jnp.int32)) > 0, l1)
+
+    l1 = None if valid is None else jnp.zeros(tol.shape, jnp.float32)
+    _, npl, grew, l1 = jax.lax.while_loop(more, fix, (0, npl, True, l1))
+    if valid is not None:
+        l1 = jax.lax.cond(grew, lambda: l1_of(errors(npl)), lambda: l1)
+    return emax, u_full, npl, l1
+
+
 def _encode_fa_kernel(x_ref, tol_ref, payload_ref, emax_ref, nplanes_ref):
     """Fixed-accuracy encode of one tile, coefficient-major.
 
     ``x_ref`` is (16, TR, 128): slab k holds coefficient k = 4r + c of
     TR x 128 blocks, one block per (row, lane).  The tile is walked
-    ``FA_SUB_ROWS`` rows at a time, so every slab is one vreg and every
-    step below is elementwise across the 16 slabs: quantize, forward lift,
-    negabinary, the plane guess (``emax - floor(log2(tol)) + GUARD_BITS``,
-    the floor read from the exponent field), zero blocks to 0 planes, then
-    the bound-verification correction run exactly ``MAX_FIX_ITERS`` times
-    (the jnp encoder's while_loop runs the identical body at most that many
-    times, a no-op on settled blocks, so this is bit-exact), and the
-    truncated pack: word w is plane 29 - 2w in bits 0..15 and plane 28 - 2w
-    in bits 16..31, bit k from coefficient k.  The correction is a loop,
-    not an unroll: on a TPU v5e ``zfp_encode_blocks_fa`` takes 2.71 ms for
-    a 1,671,168-block chunk either way, and interpret mode compiles the
-    loop in a quarter of the time.
+    ``FA_SUB_ROWS`` rows at a time, so every slab is one vreg: the plane
+    counts come from ``_fa_planes``, then the truncated pack: word w is
+    plane 29 - 2w in bits 0..15 and plane 28 - 2w in bits 16..31, bit k
+    from coefficient k.
     """
-    neg = jnp.int32(_NEG)
-
     def sub_tile(j, carry):
         rows = pl.ds(pl.multiple_of(j * FA_SUB_ROWS, FA_SUB_ROWS), FA_SUB_ROWS)
-        x = [x_ref[k, rows, :] for k in range(16)]
-        tol = tol_ref[rows, :]
-        emax = _emax_slab(functools.reduce(jnp.maximum, map(jnp.abs, x)))
-        f1, f2 = pow2_factors(Q_FIXED_POINT - emax)
-        qi = [jnp.round((v * f1) * f2).astype(jnp.int32) for v in x]
-        u_full = [(c + neg) ^ neg for c in _fwd_transform_slabs(qi)]
-
-        npl = jnp.clip(emax - floor_log2(tol) + GUARD_BITS, 0, TOTAL_PLANES)
-        npl = jnp.where(functools.reduce(jnp.bitwise_or, u_full) == 0, 0, npl)
-        g1, g2 = pow2_factors(emax - Q_FIXED_POINT)
-
-        def fix(_, npl):
-            keep = jnp.int32(-1) << jnp.clip(TOTAL_PLANES - npl, 0, 31)
-            deci = _inv_transform_slabs([((u & keep) ^ neg) - neg
-                                         for u in u_full])
-            err = functools.reduce(jnp.maximum, [
-                jnp.abs((d.astype(jnp.float32) * g1) * g2 - v)
-                for d, v in zip(deci, x)])
-            return jnp.where(err > tol, jnp.minimum(npl + 2, TOTAL_PLANES),
-                             npl)
-
-        npl = jax.lax.fori_loop(0, MAX_FIX_ITERS, fix, npl)
+        emax, u_full, npl, _ = _fa_planes(
+            [x_ref[k, rows, :] for k in range(16)], tol_ref[rows, :])
         keep = jnp.int32(-1) << jnp.clip(TOTAL_PLANES - npl, 0, 31)
         u = [v & keep for v in u_full]                # truncate kept planes
         for w in range(MAX_WORDS):
@@ -367,9 +421,7 @@ def zfp_encode_blocks_fa(coefs: jnp.ndarray, tols: jnp.ndarray,
     blocks, so flattening sample stacks is exact.
     """
     nb = coefs.shape[1]
-    rows = -(-nb // 128)
-    tile = min(FA_TILE_ROWS, -(-rows // FA_SUB_ROWS) * FA_SUB_ROWS)
-    rows = -(-rows // tile) * tile
+    rows, tile = fa_slab_rows(nb)
     pad = rows * 128 - nb
     x = jnp.pad(coefs.astype(jnp.float32), ((0, 0), (0, pad)))
     tols = jnp.pad(jnp.asarray(tols, jnp.float32), ((0, pad),),
@@ -390,6 +442,62 @@ def zfp_encode_blocks_fa(coefs: jnp.ndarray, tols: jnp.ndarray,
     )(x.reshape(16, rows, 128), tols.reshape(rows, 128))
     return (payload.reshape(MAX_WORDS, -1)[:, :nb].T,
             emax.reshape(-1)[:nb], nplanes.reshape(-1)[:nb])
+
+
+# ---------------------------------------------------------------------------
+# Algorithm 1's stats pass
+# ---------------------------------------------------------------------------
+
+def _search_stats_fa_kernel(x_ref, tol_ref, valid_ref, nplanes_ref, l1_ref):
+    """Plane counts and L1 error of one tile at its blocks' tolerances.
+
+    The tile is the encode kernel's: (16, TR, 128) coefficient slabs walked
+    ``FA_SUB_ROWS`` rows at a time, planes from the shared ``_fa_planes``.
+    In place of the pack, each block's ``sum |dec - x|`` at its final plane
+    counts, over the pixels whose bit is set in ``valid_ref`` (the edge
+    padding of a field whose H or W is not a multiple of 4 is left out).
+    """
+    def sub_tile(j, carry):
+        rows = pl.ds(pl.multiple_of(j * FA_SUB_ROWS, FA_SUB_ROWS), FA_SUB_ROWS)
+        _, _, npl, l1 = _fa_planes([x_ref[k, rows, :] for k in range(16)],
+                                   tol_ref[rows, :], valid_ref[rows, :])
+        nplanes_ref[rows, :] = npl
+        l1_ref[rows, :] = l1
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[1] // FA_SUB_ROWS, sub_tile, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def zfp_search_stats_fa(slabs: jnp.ndarray, tols: jnp.ndarray,
+                        valid: jnp.ndarray, interpret: bool = False):
+    """Pallas stats pass of Algorithm 1: what a fixed-accuracy encode at
+    ``tols`` would keep, and what it would lose, with no payload.
+
+    ((16, R, 128) f32 coefficient slabs, (R, 128) f32 tolerances, (R, 128)
+    int32 valid-pixel masks) -> ((R, 128) int32 plane counts, (R, 128) f32
+    per-block ``sum |decode - x|`` over the valid pixels).  Block b is at
+    ``[:, b // 128, b % 128]``; ``R`` is ``fa_slab_rows(nb)[0]``, so the
+    caller lays the stack out once and every pass reads it as it lies.  The
+    plane counts are ``zfp_encode_blocks_fa``'s, by the same code
+    (``_fa_planes``); pad blocks of zeros read 0 planes and, with a mask of
+    0, an L1 of 0.
+    """
+    rows = slabs.shape[1]
+    tile = min(FA_TILE_ROWS, rows)
+    assert rows % tile == 0 and tile % FA_SUB_ROWS == 0, rows
+    slab = pl.BlockSpec((tile, 128), lambda i: (i, 0))
+    return pl.pallas_call(
+        _search_stats_fa_kernel,
+        grid=(rows // tile,),
+        in_specs=[pl.BlockSpec((16, tile, 128), lambda i: (0, i, 0)),
+                  slab, slab],
+        out_specs=[slab, slab],
+        out_shape=[jax.ShapeDtypeStruct((rows, 128), jnp.int32),
+                   jax.ShapeDtypeStruct((rows, 128), jnp.float32)],
+        interpret=interpret,
+    )(slabs.astype(jnp.float32), tols.astype(jnp.float32),
+      valid.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("bits_per_value", "interpret"))

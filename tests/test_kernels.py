@@ -232,6 +232,75 @@ def test_encode_fixed_accuracy_batch_pallas_matches_jnp(rng, shape):
 
 
 # ---------------------------------------------------------------------------
+# Algorithm 1's stats kernel: bit identity with the oracle
+# ---------------------------------------------------------------------------
+
+def _stats_case(rng, case):
+    """((N, C, H, W) stack, (N,) tolerances) for one case below."""
+    shape = {"ragged": (2, 3, 30, 18), "tile_spill": (1, 1, 516, 514),
+             "zero_sample": (3, 2, 32, 32), "extremes": (2, 2, 16, 24),
+             "all_fix_steps": (1, 2, 16, 20)}[case]
+    xs = (rng.standard_normal(shape)
+          * 10.0 ** rng.uniform(-2, 2, shape[:2] + (1, 1))).astype(np.float32)
+    tols = 10.0 ** rng.uniform(-4, -1, shape[0])
+    if case == "zero_sample":
+        xs[1] = 0.0
+        tols = [1e-3, 1e-3, 10.0]
+    elif case == "extremes":
+        tols = [1e-12, 10.0]
+    elif case == "all_fix_steps":
+        # no plane count meets a negative tolerance: every block grows in
+        # all MAX_FIX_ITERS steps, so the L1 needs a decode of its own
+        xs = rng.standard_normal(shape).astype(np.float32) * 0.1
+        tols = [-1.0]
+    return jnp.asarray(xs), jnp.asarray(tols, jnp.float32)
+
+
+@pytest.mark.parametrize("case", ["ragged", "tile_spill", "zero_sample",
+                                  "extremes", "all_fix_steps"])
+def test_zfp_search_stats_fa_bit_identical(rng, case):
+    """The stats kernel in interpret mode equals its oracle block for block
+    (plane counts and L1 sums), on a field with edge padding, a stack whose
+    blocks fill neither a row of 128 nor a grid tile, an all-zero sample and
+    the tolerances 1e-12 and 10; its plane counts are the encode's, and the
+    per-sample L1 is the encode-decode roundtrip's over the unpadded field."""
+    from repro.compression import api, get_codec
+    xs, tols = _stats_case(rng, case)
+    state = api.fa_precompute_batch(xs)
+    n, nb = state.n, state.nb
+    rows = state.valid.shape[0]
+    per_block = jnp.pad(jnp.repeat(tols, nb), (0, rows * 128 - n * nb),
+                        constant_values=1.0).reshape(rows, 128)
+    got = zfp_codec.zfp_search_stats_fa(state.slabs, per_block, state.valid,
+                                        interpret=True)
+    want = ref.zfp_search_stats_fa_ref(state.slabs.reshape(16, -1).T,
+                                       per_block.reshape(-1),
+                                       state.valid.reshape(-1))
+    for name, g, w in zip(("nplanes", "l1"), got, want):
+        assert np.array_equal(np.asarray(g).reshape(-1), np.asarray(w)), name
+    # pad pixels and pad blocks are out of the sum; the rest are in
+    bits = np.asarray(state.valid).astype(np.uint32)
+    assert sum(int(np.sum((bits >> k) & 1)) for k in range(16)) == xs.size
+    assert not np.asarray(got[1]).reshape(-1)[n * nb:].any()
+
+    codec = get_codec("fixed_accuracy", backend="jnp")
+    cf = codec.encode_batch(xs, tols)
+    npl = np.asarray(got[0]).reshape(-1)[:n * nb].reshape(n, nb)
+    assert np.array_equal(npl, np.asarray(cf.nplanes))
+    l1, nbytes = api.fa_stats_batch(state, tols)
+    assert np.array_equal(np.asarray(nbytes), np.asarray(codec.nbytes(cf)))
+    mean = jnp.mean(jnp.abs(codec.decode_batch(cf) - xs), axis=(1, 2, 3))
+    np.testing.assert_allclose(np.asarray(l1), np.asarray(mean), rtol=1e-5)
+    if case == "zero_sample":
+        assert float(l1[1]) == 0.0 and not npl[1].any()
+    if case == "all_fix_steps":
+        from repro.compression.transform import GUARD_BITS, MAX_FIX_ITERS
+        guess = np.clip(np.asarray(cf.emax) + GUARD_BITS, 0, T.TOTAL_PLANES)
+        assert (guess + 2 * MAX_FIX_ITERS <= T.TOTAL_PLANES).all()
+        assert np.array_equal(npl, guess + 2 * MAX_FIX_ITERS)
+
+
+# ---------------------------------------------------------------------------
 # flash attention kernel vs oracle
 # ---------------------------------------------------------------------------
 

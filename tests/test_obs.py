@@ -384,6 +384,8 @@ class TestSpansOnTheProfiler:
         tracer = obs_trace.configure(None, run="certify")
         xs = np.random.default_rng(0).normal(size=(2, 8, 8)).astype(
             np.float32)
+        passes = obs_metrics.get_registry().counter("tolerance.stats_passes")
+        before = passes.value
         res = find_tolerance_batch(xs, np.full(2, 0.02, np.float32))
         get_codec("fixed_accuracy", backend="jnp").encode_batch(
             jnp.asarray(xs), jnp.asarray(res.tolerance))
@@ -395,6 +397,8 @@ class TestSpansOnTheProfiler:
         assert (readback["ts"] + readback["dur"]
                 <= search["ts"] + search["dur"] + 1e-9)
         assert search["args"]["max_iterations"] >= 1
+        # one stats pass of the whole stack per iteration of the search
+        assert passes.value - before == search["args"]["max_iterations"]
         assert evs["codec.encode_batch"]["args"]["samples"] == 2
 
 

@@ -66,6 +66,7 @@ def _kernel_cases(sharding):
     payload = _spec((NB, MAX_WORDS), i32, sharding)
     per_block = _spec((NB,), i32, sharding)
     blocks = _spec((NB, 16), f32, sharding)
+    rows, _ = zfp_codec.fa_slab_rows(NB)
     return {
         "decode": lambda: zfp_codec.zfp_decode_blocks.lower(
             payload, per_block, bits_per_value=TOTAL_PLANES),
@@ -75,11 +76,15 @@ def _kernel_cases(sharding):
             blocks, bits_per_value=TOTAL_PLANES),
         "encode_fa": lambda: zfp_codec.zfp_encode_blocks_fa.lower(
             _spec((16, NB), f32, sharding), _spec((NB,), f32, sharding)),
+        "search_stats_fa": lambda: zfp_codec.zfp_search_stats_fa.lower(
+            _spec((16, rows, 128), f32, sharding),
+            _spec((rows, 128), f32, sharding),
+            _spec((rows, 128), i32, sharding)),
     }
 
 
 @pytest.mark.parametrize("kernel", ["decode", "decode_fa", "encode",
-                                    "encode_fa"])
+                                    "encode_fa", "search_stats_fa"])
 def test_codec_kernel_compiles_for_v5e(one_chip, kernel):
     compiled = _kernel_cases(one_chip)[kernel]().compile()
     assert "tpu_custom_call" in compiled.as_text()
@@ -95,6 +100,23 @@ def test_certify_chunk_encode_is_lane_dense(one_chip):
         _spec(CERTIFY_CHUNK, jnp.float32, one_chip),
         _spec(CERTIFY_CHUNK[:1], jnp.float32, one_chip)).compile()
     assert re.search(r"%zfp_encode_blocks_fa\.\d+ = ", compiled.as_text())
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_search_is_one_stats_kernel_per_pass(one_chip):
+    """Algorithm 1's search over one PCHIP member (51 snapshots) runs each
+    pass as the stats kernel's custom call, under a name that
+    ``bench/metrics/encode_roofline.py`` does not count as the encode, on
+    the coefficient-major layout: the block-major search took 2,375 MiB of
+    temporaries here."""
+    from repro.core.tolerance import _search_batch
+    member = (51,) + CERTIFY_CHUNK[1:]
+    compiled = _search_batch.lower(
+        _spec(member, jnp.float32, one_chip),
+        _spec(member[:1], jnp.float32, one_chip), 2, 8).compile()
+    text = compiled.as_text()
+    assert re.search(r"%zfp_search_stats_fa\.\d+ = ", text)
+    assert "%zfp_encode_blocks_fa." not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
 
 
